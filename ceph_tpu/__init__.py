@@ -26,13 +26,3 @@ scalar specification).
 """
 
 __version__ = "0.1.0"
-
-# Honor CEPH_TPU_PLATFORM for EVERY library entry point, not just the
-# CLIs: deployment images may preload jax pinned to a hardware backend,
-# so the env var alone is a no-op; routing it through jax.config here
-# (cheap — no backend client is created) makes
-# ``CEPH_TPU_PLATFORM=cpu python anything_importing_ceph_tpu.py`` work.
-from .utils.platform import apply_platform_env as _apply_platform_env
-
-_apply_platform_env()
-del _apply_platform_env

@@ -92,6 +92,10 @@ REGISTRY: Dict[str, Dict[str, str]] = {
         "encode_bytes": U64,
         "decode_bytes": U64,
         "jit_compiles": U64,
+        # ops that ran as a compiled device kernel (native-engine ops
+        # book none; a Pallas kernel in interpret mode books the next)
+        "device_launches": U64,
+        "interpret_launches": U64,
         "encode_time": TIME,
         "decode_time": TIME,
         "jit_compile_time": TIME,

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import functools
 import os
+import time
 from typing import Optional, Tuple
 
 import numpy as np
@@ -849,11 +850,65 @@ def book_map_batch(sig, dt: float, n_xs: int, result_max: int,
             h2d_bytes=h2d_bytes, d2h_bytes=d2h_bytes)
 
 
+def launch_budget_bytes() -> int:
+    """Bytes one mapper launch may claim on the default device: half of
+    what the backend reports free, or, where it reports nothing (the
+    CPU backend), half of the host memory the OS reports available."""
+    stats = jax.devices()[0].memory_stats()
+    if stats and "bytes_limit" in stats:
+        free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+    else:
+        free = os.sysconf("SC_AVPHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+    return free // 2
+
+
+PROBE_LANES = 4096   # lanes of the program that measures the footprint
+
+
+def footprint_bytes(compiled) -> int:
+    """Device bytes a compiled launch claims: its arguments, output and
+    scratch, as ``memory_analysis()`` reports them."""
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes +
+            ma.temp_size_in_bytes)
+
+
+def fit_lanes(n: int, compile_at, budget: int) -> int:
+    """Lanes per launch for ``n`` inputs within ``budget`` bytes.
+
+    ``compile_at(lanes)`` returns the program compiled for that many
+    lanes.  A program too large for the device never compiles, so the
+    footprint is read from a probe of ``min(n, PROBE_LANES)`` lanes
+    (the launch itself when ``n`` is no larger) and charged to its
+    lanes in full: the map arrays' fixed share makes that an upper
+    bound per lane (measured for a v5e on map_big10k: the general rule
+    VM about 540 KB per lane at any size, the speculative lowering
+    5.5 KB at the probe and 2.8 KB at 2^20 lanes).  All ``n`` lanes
+    when they fit, else the largest power of two that does."""
+    probe = min(n, PROBE_LANES)
+    per_lane = footprint_bytes(compile_at(probe)) / probe
+    fit = int(budget // per_lane)
+    if fit < 1:
+        raise MemoryError(f"one lane needs {per_lane:.0f} bytes of a "
+                          f"{budget}-byte launch budget")
+    return n if fit >= n else 1 << (fit.bit_length() - 1)
+
+
 class BatchedMapper:
     """User-facing handle: compile-per-rule cache + array residency.
 
     >>> m = BatchedMapper(cmap)
     >>> res, lens = m.map_batch(ruleno, xs, result_max, weight)
+
+    Rules the speculative lowering accepts (``mapper_spec``: straw2
+    take/chooseleaf/emit under modern tunables) run on it, as
+    ``PoolMapper`` does; the rest take the general rule VM.
+    ``CEPH_TPU_SPEC_PIPELINE=0`` forces the general VM for both.
+
+    A batch of any length maps in launches that fit the device: the
+    launch size is read from a small compiled probe's
+    ``memory_analysis()`` against :func:`launch_budget_bytes`
+    (:func:`fit_lanes`).
 
     ``mesh``: a ``jax.sharding.Mesh`` routes every ``map_batch``
     through the mesh-sharded ``parallel.PlacementPlane`` (PG axis
@@ -867,7 +922,8 @@ class BatchedMapper:
         self.cmap = cmap
         self.choose_args = choose_args
         self._cache = {}
-        self._compiled_sigs: set = set()  # (rule, result_max, N)
+        self._lanes = {}   # (rule, result_max, N) -> launch lanes
+        self._exe = {}     # (rule, result_max, lanes) -> compiled
         self._encoded = encode_map(cmap, choose_args)
         self._arrays = jax.tree_util.tree_map(
             jnp.asarray, self._encoded[1])
@@ -883,35 +939,79 @@ class BatchedMapper:
     def rule_fn(self, ruleno: int, result_max: int):
         key = (ruleno, result_max)
         if key not in self._cache:
-            fn, static, _ = build_rule_fn(
-                self.cmap, ruleno, result_max, self.choose_args,
-                encoded=self._encoded)
-            self._cache[key] = (fn, static)
-        return self._cache[key][0]
+            fn = None
+            if os.environ.get("CEPH_TPU_SPEC_PIPELINE", "1") != "0":
+                from .mapper_spec import Ineligible, build_spec_rule_fn
+
+                try:
+                    fn, _, _ = build_spec_rule_fn(
+                        self.cmap, ruleno, result_max, self.choose_args,
+                        encoded=self._encoded, k_tries=1)
+                except Ineligible:
+                    fn = None
+            if fn is None:
+                fn, _, _ = build_rule_fn(
+                    self.cmap, ruleno, result_max, self.choose_args,
+                    encoded=self._encoded)
+            self._cache[key] = fn
+        return self._cache[key]
 
     @property
     def arrays(self) -> MapArrays:
         return self._arrays
 
+    def _compiled(self, ruleno: int, result_max: int, lanes: int,
+                  weight):
+        key = (ruleno, result_max, lanes)
+        exe = self._exe.get(key)
+        if exe is None:
+            t0 = time.monotonic()
+            exe = self.rule_fn(ruleno, result_max).lower(
+                self._arrays, weight,
+                jax.ShapeDtypeStruct((lanes,), jnp.uint32)).compile()
+            _pc.inc("jit_compiles")
+            _pc.tinc("jit_compile_time", time.monotonic() - t0)
+            self._exe[key] = exe
+        return exe
+
+    def _launch_lanes(self, ruleno: int, result_max: int, n: int,
+                      weight) -> int:
+        key = (ruleno, result_max, n)
+        if key not in self._lanes:
+            self._lanes[key] = fit_lanes(
+                n, lambda lanes: self._compiled(ruleno, result_max,
+                                                lanes, weight),
+                launch_budget_bytes())
+        return self._lanes[key]
+
     def map_batch(self, ruleno: int, xs, result_max: int, weight):
         """Map a batch: xs uint32[N], weight 16.16 uint32[max_devices]."""
-        import time
-
         if self._plane is not None:
             return self._plane.map_batch(ruleno, xs, result_max, weight)
-        fn = self.rule_fn(ruleno, result_max)
-        xs = jnp.asarray(np.asarray(xs, np.uint32))
+        xs = np.asarray(xs, np.uint32)
+        n = int(xs.shape[0])
+        if n == 0:
+            return (jnp.zeros((0, result_max), I32),
+                    jnp.zeros((0,), I32))
         weight = jnp.asarray(np.asarray(weight, np.uint32))
-        t0 = time.monotonic()
-        out = fn(self._arrays, weight, xs)
-        dt = time.monotonic() - t0
-        sig = (ruleno, result_max, tuple(xs.shape))
-        first = sig not in self._compiled_sigs
-        if first:
-            self._compiled_sigs.add(sig)
-        # device plane: xs + weight cross host->device, the result
-        # block (results + lens, i32) crosses back when consumed
-        book_map_batch(sig, dt, int(xs.shape[0]), result_max, first,
-                       h2d_bytes=int(xs.size) * 4 + int(weight.size) * 4,
-                       d2h_bytes=int(xs.shape[0]) * (result_max + 1) * 4)
-        return out
+        lanes = self._launch_lanes(ruleno, result_max, n, weight)
+        exe = self._compiled(ruleno, result_max, lanes, weight)
+        res, lens = [], []
+        for lo in range(0, n, lanes):
+            chunk = xs[lo:lo + lanes]
+            if len(chunk) < lanes:   # the tail pads with x=0 lanes
+                chunk = np.pad(chunk, (0, lanes - len(chunk)))
+            t0 = time.monotonic()
+            r, ln = exe(self._arrays, weight, jnp.asarray(chunk))
+            # device plane: xs + weight cross host->device, the result
+            # block (results + lens, i32) crosses back when consumed
+            book_map_batch((ruleno, result_max, (lanes,)),
+                           time.monotonic() - t0, lanes, result_max,
+                           False, h2d_bytes=lanes * 4 +
+                           int(weight.size) * 4,
+                           d2h_bytes=lanes * (result_max + 1) * 4)
+            res.append(r)
+            lens.append(ln)
+        if len(res) == 1 and lanes == n:
+            return res[0], lens[0]
+        return (jnp.concatenate(res)[:n], jnp.concatenate(lens)[:n])

@@ -2,17 +2,16 @@
 
 The host-side hot loops (tools' scalar sweeps, the bench's CPU
 fallback) run the batched C++ mapper over the SAME SoA arrays the TPU
-mapper consumes; Python remains the source of truth (mapper_ref) and
-the graceful fallback when the library isn't built.
+mapper consumes; Python remains the source of truth (mapper_ref).
 
-``ensure_built()`` invokes the Makefile once per process if the .so is
-missing (the toolchain is part of the image); failures degrade to
-None — callers fall back to the Python/JAX paths.
+``ensure_built()`` runs the Makefile once per process (the toolchain
+is part of the image); a failed build raises.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import pathlib
 import subprocess
 from typing import List, Optional, Tuple
@@ -30,36 +29,36 @@ LIB_PATH = NATIVE_DIR / "libcrush_host.so"
 
 _lock = make_lock("crush::native_build")
 _lib: Optional[ctypes.CDLL] = None
-_build_failed = False
+_build_error: Optional[str] = None
 
 _i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
 _u32p = np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS")
 _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 
 
-def ensure_built() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None on failure."""
-    global _lib, _build_failed
+def ensure_built() -> ctypes.CDLL:
+    """Load the native library, building it from the committed sources
+    first.  A failed build raises: a library left over from an earlier
+    build is never loaded in its place."""
+    global _lib, _build_error
     with _lock:
         if _lib is not None:
             return _lib
-        if _build_failed:
-            return None
+        if _build_error is not None:
+            raise RuntimeError(_build_error)
         # always run make: a no-op when fresh, and source edits never
-        # load a stale library (the Makefile carries the deps)
-        try:
-            subprocess.run(["make", "-s"], cwd=str(NATIVE_DIR),
-                           check=True, capture_output=True,
-                           timeout=120)
-        except Exception:
-            if not LIB_PATH.exists():
-                _build_failed = True
-                return None
-        try:
-            lib = ctypes.CDLL(str(LIB_PATH))
-        except OSError:
-            _build_failed = True
-            return None
+        # load a stale library (the Makefile carries the deps).  The
+        # flock serializes builds across processes (test workers).
+        with open(NATIVE_DIR / ".build.lock", "w") as lk:
+            fcntl.flock(lk, fcntl.LOCK_EX)
+            proc = subprocess.run(["make", "-s"], cwd=str(NATIVE_DIR),
+                                  capture_output=True, text=True,
+                                  timeout=120)
+        if proc.returncode != 0:
+            _build_error = (f"native build failed (make exit "
+                            f"{proc.returncode}): {proc.stderr[-2000:]}")
+            raise RuntimeError(_build_error)
+        lib = ctypes.CDLL(str(LIB_PATH))
         lib.crush_do_rule_batched.restype = ctypes.c_int
         lib.crush_do_rule_batched.argtypes = [
             ctypes.c_int, ctypes.c_int, ctypes.c_int, ctypes.c_int,
@@ -78,19 +77,12 @@ def ensure_built() -> Optional[ctypes.CDLL]:
         return _lib
 
 
-def available() -> bool:
-    return ensure_built() is not None
-
-
 class NativeMapper:
     """Batched do_rule on the C++ engine for one (map, choose_args)."""
 
     def __init__(self, cmap: CrushMap,
                  choose_args: Optional[ChooseArgMap] = None):
-        lib = ensure_built()
-        if lib is None:
-            raise RuntimeError("native crush mapper unavailable")
-        self._lib = lib
+        self._lib = ensure_built()
         self.cmap = cmap
         self.static, arr = encode_map(cmap, choose_args)
         self._a = {

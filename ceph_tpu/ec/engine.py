@@ -49,7 +49,8 @@ _BITS8 = np.arange(8, dtype=np.uint8)
 # histograms are not polluted by tracing+compilation.
 _pc = collection().create("ec.engine")
 for _k in ("encode_ops", "decode_ops", "encode_bytes",
-           "decode_bytes", "jit_compiles"):
+           "decode_bytes", "jit_compiles", "device_launches",
+           "interpret_launches"):
     _pc.add_u64_counter(_k)
 for _k in ("encode_time", "decode_time", "jit_compile_time"):
     _pc.add_time(_k)
@@ -93,14 +94,17 @@ def encode_batched_sharded(code: "BitCode", stripes, mesh,
 
 def _account(kind: str, sig: tuple, dt: float, nbytes: int,
              jitted: bool = True, nbytes_out: int = 0,
-             device_ids=None) -> None:
+             device_ids=None, interpret: bool = False) -> None:
     """Shared by every EC execution engine (the jitted bit-plane path
     here and native_gf's table engine, which passes jitted=False —
     it has no compile step to separate out).  Jitted launches also
     book into the device plane: the input bytes cross host->device,
     the materialized output crosses back (common/device_metrics.py,
     per-shape-signature).  Mesh launches pass ``device_ids`` so every
-    participating chip books a per-device row too."""
+    participating chip books a per-device row too.  A Pallas kernel
+    run in interpret mode (``interpret``) books ``interpret_launches``
+    instead of ``device_launches``: only a compiled kernel counts as a
+    device launch."""
     _pc.inc(f"{kind}_ops")
     _pc.inc(f"{kind}_bytes", nbytes)
     if jitted and sig not in _seen_sigs:
@@ -111,6 +115,10 @@ def _account(kind: str, sig: tuple, dt: float, nbytes: int,
         _pc.tinc(f"{kind}_time", dt)
         _pc.hist_add(f"{kind}_lat", dt)
     if jitted:
+        if interpret:
+            _pc.inc("interpret_launches")
+        else:
+            _pc.inc("device_launches")
         if device_ids:
             device_metrics.record_mesh_launch(
                 "ec.engine", f"{kind}:{sig}", dt, device_ids,
@@ -263,9 +271,10 @@ class BitCode:
         self.layout.check(data.shape[1])
         t0 = time.monotonic()
         pk = self._fused_w8()
+        interp = pk is not None and not pk.on_tpu()
         if pk is not None:
             out = pk.fused_gf2_matmul_w8(self._enc_dev, data,
-                                         interpret=not pk.on_tpu())
+                                         interpret=interp)
         else:
             rows = self.layout.to_rows(data)
             out = self.layout.from_rows(
@@ -276,7 +285,8 @@ class BitCode:
                   self.layout.w, self.layout.packetsize,
                   pk is not None),
                  time.monotonic() - t0, int(data.size),
-                 nbytes_out=self.m * int(data.shape[1]))
+                 nbytes_out=self.m * int(data.shape[1]),
+                 interpret=interp)
         return out
 
     def encode_batched(self, stripes, mesh=None):
@@ -310,9 +320,10 @@ class BitCode:
         t0 = time.monotonic()
         flat = stripes.transpose(1, 0, 2).reshape(self.k, B * L)
         pk = self._fused_w8()
+        interp = pk is not None and not pk.on_tpu()
         if pk is not None:
             out = pk.fused_gf2_matmul_w8(self._enc_dev, flat,
-                                         interpret=not pk.on_tpu())
+                                         interpret=interp)
         else:
             rows = self.layout.to_rows(flat)
             out = self.layout.from_rows(
@@ -323,7 +334,7 @@ class BitCode:
                   self.layout.w, self.layout.packetsize,
                   pk is not None),
                  time.monotonic() - t0, int(stripes.size),
-                 nbytes_out=B * self.m * L)
+                 nbytes_out=B * self.m * L, interpret=interp)
         book_batch(B)
         return out
 
@@ -381,6 +392,7 @@ class BitCode:
                 [stripes, jnp.zeros((Bp - B, k, L), jnp.uint8)],
                 axis=0)
         pk = self._fused_w8()
+        interp = pk is not None and not pk.on_tpu()
         if pk is not None:
             # fused mesh path: split the padded batch evenly, flatten
             # each shard along the byte axis ((b, k, L) -> (k, b*L) —
@@ -390,7 +402,6 @@ class BitCode:
             # columns.
             devs = list(np.asarray(mesh.devices).ravel())  # jax-ok: mesh.devices is a host-side numpy array of Device handles
             per = Bp // n_dev
-            interp = not pk.on_tpu()
             parts = []
             for d, grp in zip(devs, jnp.split(stripes, n_dev)):
                 flat = jax.device_put(
@@ -415,7 +426,8 @@ class BitCode:
                  time.monotonic() - t0, B * k * L,
                  nbytes_out=B * self.m * L,
                  device_ids=[int(d.id) for d in
-                             np.asarray(mesh.devices).ravel()])  # jax-ok: mesh.devices is a host-side numpy array of Device handles
+                             np.asarray(mesh.devices).ravel()],  # jax-ok: mesh.devices is a host-side numpy array of Device handles
+                 interpret=interp)
         book_batch(B)
         return out
 
@@ -452,9 +464,9 @@ class BitCode:
         self.layout.check(L)
         t0 = time.monotonic()
         pk = self._fused_w8()
+        interp = pk is not None and not pk.on_tpu()
         if pk is not None:
-            out = pk.fused_gf2_matmul_w8(inv, stack,
-                                         interpret=not pk.on_tpu())
+            out = pk.fused_gf2_matmul_w8(inv, stack, interpret=interp)
         else:
             rows = self.layout.to_rows(stack)
             out = self.layout.from_rows(_mod2_matmul(inv, rows),
@@ -464,7 +476,7 @@ class BitCode:
                   self.layout.w, self.layout.packetsize,
                   pk is not None),
                  time.monotonic() - t0, int(stack.size),
-                 nbytes_out=self.k * int(L))
+                 nbytes_out=self.k * int(L), interpret=interp)
         return out
 
     def decode(self, want: Sequence[int], chunks: Dict[int, "jnp.ndarray"]):

@@ -59,15 +59,17 @@ def _call(bm, data, k: int, m: int, interpret: bool, tile: int):
 
     L = data.shape[1]
     grid = (L // tile,)
+    # block indices stay int32: the CRUSH mapper turns x64 on for the
+    # whole process, and Mosaic cannot lower an i64 index map
     return pl.pallas_call(
         functools.partial(_kernel, k=k, m=m),
         out_shape=jax.ShapeDtypeStruct((m, L), jnp.uint8),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((8 * m, 8 * k), lambda i: (0, 0)),
-            pl.BlockSpec((k, tile), lambda i: (0, i)),
+            pl.BlockSpec((8 * m, 8 * k), lambda i: (i * 0, i * 0)),
+            pl.BlockSpec((k, tile), lambda i: (i * 0, i)),
         ],
-        out_specs=pl.BlockSpec((m, tile), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((m, tile), lambda i: (i * 0, i)),
         interpret=interpret,
     )(bm, data)
 
@@ -91,7 +93,8 @@ def fused_gf2_matmul_w8(bm_bits, data, interpret: bool = False):
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """The kernel compiles for the chip on the TPU backend and runs in
+    interpret mode on every other backend.  A backend that fails to
+    initialize raises here rather than passing for one without a
+    chip."""
+    return jax.default_backend() == "tpu"

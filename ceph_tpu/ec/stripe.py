@@ -145,110 +145,37 @@ def recover_stripes(sinfo: StripeInfo, code: ErasureCode,
 
 
 # -- crc32c (Castagnoli) — HashInfo (ECUtil.h:164-180) ----------------------
-#
-# The byte update s' = T[(s ^ b) & 0xFF] ^ (s >> 8) is GF(2)-LINEAR
-# (T[x ^ y] = T[x] ^ T[y]), so crc(seed, block) =
-# shift_B(seed) ^ crc(0, block), and crc(0, block) is an XOR of
-# per-(position, byte) contributions — a numpy gather + XOR-reduce per
-# block, with only one tiny table-lookup shift per block left in
-# Python.  This keeps HashInfo viable on the data path (per-byte
-# Python would cost seconds per multi-MiB shard).
-
-_CRC32C_POLY = 0x82F63B78
-_CRC_BLOCK = 512
-_crc_tables: dict = {}
-
-
-def _crc_setup():
-    if _crc_tables:
-        return _crc_tables
-    tbl = np.zeros(256, np.uint32)
-    for i in range(256):
-        c = i
-        for _ in range(8):
-            c = (c >> 1) ^ _CRC32C_POLY if c & 1 else c >> 1
-        tbl[i] = c
-
-    def shift1(v):  # advance one zero byte (vectorized)
-        return tbl[v & np.uint32(0xFF)] ^ (v >> np.uint32(8))
-
-    # pos_tbl[p, b]: crc(0, block with byte b at p, zeros elsewhere)
-    pos = np.zeros((_CRC_BLOCK, 256), np.uint32)
-    pos[_CRC_BLOCK - 1] = tbl
-    for p in range(_CRC_BLOCK - 2, -1, -1):
-        pos[p] = shift1(pos[p + 1])
-
-    # shift_B as two 16-bit half-state tables
-    basis = np.asarray([1 << i for i in range(32)], np.uint32)
-    for _ in range(_CRC_BLOCK):
-        basis = shift1(basis)
-    idx = np.arange(1 << 16, dtype=np.uint32)
-    sh_lo = np.zeros(1 << 16, np.uint32)
-    sh_hi = np.zeros(1 << 16, np.uint32)
-    for i in range(16):
-        bit = (idx >> np.uint32(i)) & np.uint32(1)
-        sh_lo ^= np.where(bit == 1, basis[i], np.uint32(0))
-        sh_hi ^= np.where(bit == 1, basis[16 + i], np.uint32(0))
-    _crc_tables.update(tbl=tbl, pos=pos, sh_lo=sh_lo, sh_hi=sh_hi)
-    return _crc_tables
-
 
 _native_crc = None
 
 
 def _native_crc32c():
     """The slicing-by-8 C engine (native/crush_host.cpp crc32c_sb8) —
-    the src/common/crc32c.h hot-path role; bit-equality with the
-    Python table walker below is pinned by tests/test_stripe.py."""
+    the src/common/crc32c.h hot-path role.  A failed native build
+    raises (``native.ensure_built``)."""
     global _native_crc
     if _native_crc is None:
-        try:
-            import ctypes
+        import ctypes
 
-            from ..crush.native import ensure_built
+        from ..crush.native import ensure_built
 
-            lib = ensure_built()
-            if lib is None:
-                _native_crc = False
-            else:
-                lib.crc32c_sb8.restype = ctypes.c_uint32
-                lib.crc32c_sb8.argtypes = [
-                    ctypes.c_uint32,
-                    np.ctypeslib.ndpointer(np.uint8,
-                                           flags="C_CONTIGUOUS"),
-                    ctypes.c_int64]
-                _native_crc = lib.crc32c_sb8
-        except Exception:
-            _native_crc = False
-    return _native_crc or None
+        lib = ensure_built()
+        lib.crc32c_sb8.restype = ctypes.c_uint32
+        lib.crc32c_sb8.argtypes = [
+            ctypes.c_uint32,
+            np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS"),
+            ctypes.c_int64]
+        _native_crc = lib.crc32c_sb8
+    return _native_crc
 
 
 def crc32c(data: bytes | np.ndarray, crc: int = 0xFFFFFFFF) -> int:
     """ceph_crc32c semantics (seed as passed, no final xor; the OSD
     uses -1)."""
-    fn = _native_crc32c()
-    if fn is not None:
-        buf = np.frombuffer(data, np.uint8) if isinstance(
-            data, (bytes, bytearray, memoryview)) \
-            else np.ascontiguousarray(np.asarray(data, np.uint8).ravel())
-        return int(fn(crc & 0xFFFFFFFF, buf, len(buf)))
-    t = _crc_setup()
     buf = np.frombuffer(data, np.uint8) if isinstance(
-        data, (bytes, bytearray, memoryview)) else \
-        np.asarray(data, np.uint8).ravel()
-    s = int(crc) & 0xFFFFFFFF
-    nb = len(buf) // _CRC_BLOCK
-    if nb:
-        blocks = buf[:nb * _CRC_BLOCK].reshape(nb, _CRC_BLOCK)
-        contrib = t["pos"][np.arange(_CRC_BLOCK)[None, :], blocks]
-        block_crcs = np.bitwise_xor.reduce(contrib, axis=1).tolist()
-        sh_lo, sh_hi = t["sh_lo"], t["sh_hi"]
-        for c in block_crcs:
-            s = int(sh_lo[s & 0xFFFF]) ^ int(sh_hi[s >> 16]) ^ c
-    tbl = t["tbl"]
-    for b in buf[nb * _CRC_BLOCK:].tobytes():
-        s = int(tbl[(s ^ b) & 0xFF]) ^ (s >> 8)
-    return s
+        data, (bytes, bytearray, memoryview)) \
+        else np.ascontiguousarray(np.asarray(data, np.uint8).ravel())
+    return int(_native_crc32c()(crc & 0xFFFFFFFF, buf, len(buf)))
 
 
 class HashInfo:

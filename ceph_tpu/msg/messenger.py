@@ -206,6 +206,9 @@ def _reap_writer(sock) -> None:
         _sock_writers.pop(id(sock), None)
 
 _UNACKED_CAP = 512      # frames buffered per lossless peer session
+# connect() deadline, kept afterwards as a per-send deadline only: a
+# reply may take as long as its op takes, so reads never time out
+_CONNECT_TIMEOUT = 5.0
 _REPLY_CACHE_CAP = 128  # replies cached per remote session
 
 # call-correlation tids: random per-process prefix + counter.  As
@@ -1362,9 +1365,18 @@ class Messenger:
             sock = self._conns.get(addr)
             if sock is not None:
                 return sock
-            sock = socket.create_connection(addr, timeout=5)
+            sock = socket.create_connection(addr,
+                                            timeout=_CONNECT_TIMEOUT)
             sock.setsockopt(socket.IPPROTO_TCP,
                             socket.TCP_NODELAY, 1)
+            # a socket timeout would also bound the reader's recv and
+            # drop the connection under any reply slower than it; the
+            # kernel send timeout bounds writes to a wedged peer alone
+            sock.settimeout(None)
+            sec = int(_CONNECT_TIMEOUT)
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDTIMEO,
+                            struct.pack("ll", sec, int(
+                                (_CONNECT_TIMEOUT - sec) * 1e6)))
             self._conns[addr] = sock
             threading.Thread(target=self._reader, args=(sock, addr),
                              daemon=True,

@@ -35,9 +35,10 @@ def load_map(path: str) -> CrushWrapper:
     stripped = content.lstrip()
     if stripped.startswith("{"):
         d = json.loads(content)
-        if "map" in d:
+        if "type_map" in d:
             return CrushWrapper.from_dict(d)
-        return CrushWrapper(CrushMap.from_dict(d))
+        # a bare CrushMap, or a golden file ({"map": ..., "cases": ...})
+        return CrushWrapper(CrushMap.from_dict(d.get("map", d)))
     return compile_crushmap(content)
 
 

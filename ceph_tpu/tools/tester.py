@@ -5,9 +5,10 @@ of inputs, tally per-device utilization against the weight-proportional
 expectation, report result-size statistics, bad mappings, and compare
 two maps.  Where the reference loops ``crush.do_rule`` one x at a time
 (:573, the hot loop the 50x BASELINE target measures), this engine maps
-the whole x range in ONE batched launch (``BatchedMapper``) and derives
-every statistic from the result arrays; ``scalar=True`` routes through
-the executable spec instead (tiny runs, no compile cost).
+the whole x range in batched launches sized to the device
+(``BatchedMapper``) and derives every statistic from the result
+arrays; ``scalar=True`` routes through the executable spec instead
+(tiny runs, no compile cost).
 """
 
 from __future__ import annotations
@@ -75,72 +76,55 @@ class CrushTester:
         if pool is not None:
             xs = np.asarray([hash32_2_int(int(x), pool) for x in xs],
                             np.uint32)  # CrushTester.cc:570-572
+        weights = np.asarray(self.weights, np.uint32)
         counts = None
         if scalar:
-            results = [crush_do_rule(cmap, ruleno, int(x), num_rep,
-                                     self.weights) for x in xs]
-            lens = [len(r) for r in results]
+            rows = [crush_do_rule(cmap, ruleno, int(x), num_rep,
+                                  self.weights) for x in xs]
+            ln = np.asarray([len(r) for r in rows], np.int32)
+            res = np.full((len(xs), max(1, num_rep)), -1, np.int32)
+            for i, r in enumerate(rows):
+                res[i, :len(r)] = r
         elif mesh is not None:
             from ..parallel.placement import PlacementPlane
 
             plane = PlacementPlane(cmap, mesh=mesh)
             res, ln, counts = plane.map_batch(
-                ruleno, xs, num_rep,
-                np.asarray(self.weights, np.uint32),
-                gather_stats=True)
-            res, ln = np.asarray(res), np.asarray(ln)
-            counts = np.asarray(counts)
-            results = [list(res[i, :ln[i]]) for i in range(len(xs))]
-            lens = list(ln)
+                ruleno, xs, num_rep, weights, gather_stats=True)
         elif native:
             from ..crush.native import NativeMapper
 
-            nm = NativeMapper(cmap)
-            res, ln = nm.map_batch(
-                ruleno, xs, num_rep,
-                np.asarray(self.weights, np.uint32))
-            results = [list(res[i, :ln[i]]) for i in range(len(xs))]
-            lens = list(ln)
+            res, ln = NativeMapper(cmap).map_batch(
+                ruleno, xs, num_rep, weights)
         else:
             from ..crush.mapper_jax import BatchedMapper
 
-            bm = BatchedMapper(cmap)
-            res, ln = bm.map_batch(
-                ruleno, xs, num_rep,
-                np.asarray(self.weights, np.uint32))
-            res, ln = np.asarray(res), np.asarray(ln)
-            results = [list(res[i, :ln[i]]) for i in range(len(xs))]
-            lens = list(ln)
+            res, ln = BatchedMapper(cmap).map_batch(
+                ruleno, xs, num_rep, weights)
+        res, ln = np.asarray(res), np.asarray(ln)
 
         rep = RuleReport(ruleno, num_rep, min_x, max_x)
         rep.total = len(xs)
         n_dev = cmap.max_devices
+        sizes, nsize = np.unique(ln, return_counts=True)
+        rep.size_counts = {int(s): int(c) for s, c in zip(sizes, nsize)}
         if counts is not None:
-            # the plane's all-reduced on-device tally IS the stats
-            # pass — only the size histogram stays host-side
-            stored = counts.astype(np.int64)
-            for r in results:
-                rep.size_counts[len(r)] = \
-                    rep.size_counts.get(len(r), 0) + 1
+            # the plane's all-reduced on-device tally IS the stats pass
+            stored = np.asarray(counts).astype(np.int64)
         else:
-            stored = np.zeros(n_dev, np.int64)
-            for r in results:
-                rep.size_counts[len(r)] = \
-                    rep.size_counts.get(len(r), 0) + 1
-                for o in r:
-                    if 0 <= o < n_dev:
-                        stored[o] += 1
+            placed = res[np.arange(res.shape[1])[None, :] < ln[:, None]]
+            placed = placed[(placed >= 0) & (placed < n_dev)]
+            stored = np.bincount(placed, minlength=n_dev).astype(np.int64)
         rep.device_stored = stored
         # expected: weight-proportional share of all placed replicas
         wv = np.asarray(self.weights[:n_dev], np.float64)
         placed = stored.sum()
         rep.device_expected = (wv / wv.sum() * placed) if wv.sum() \
             else np.zeros(n_dev)
-        for i, r in enumerate(results):
-            if len(r) != num_rep:
-                rep.bad.append((int(xs[i]), r))
+        for i in np.flatnonzero(ln != num_rep):
+            rep.bad.append((int(xs[i]), res[i, :ln[i]].tolist()))
         if collect_mappings:
-            rep.mappings = results
+            rep.mappings = [r[:n].tolist() for r, n in zip(res, ln)]
         return rep
 
     # -- compare (CrushTester.cc:682-747) ------------------------------

@@ -71,8 +71,7 @@ def test_rados_bench_qd_sweep_smoke():
 
 
 def test_bench_init_probe_fail_fast():
-    """The staged-lane backend-init probe (satellite regression for
-    the BENCH_r05 300 s hang): a worker that never emits its init
+    """The staged-lane backend-init probe: a worker that never emits its init
     line must be declared dead at INIT_DEADLINE (60 s default), not
     at the full worker deadline — checked here with a tiny deadline
     against a sleeping child."""
@@ -92,6 +91,10 @@ def test_bench_init_probe_fail_fast():
         dt = time.monotonic() - t0
         assert got is None, "no init line must mean probe failure"
         assert dt < 5.0, f"probe waited {dt:.1f}s past its deadline"
+        # kill returns only once the worker is gone: the next worker
+        # may open the chip right after it
+        stream.kill("test")
+        assert proc.poll() is not None
     finally:
         proc.kill()
         proc.wait()

@@ -333,7 +333,7 @@ def test_map_epoch_catchup(cluster):
 
 
 def test_ec_partial_stripe_overwrite(cluster):
-    """VERDICT #7 acceptance: non-aligned overwrites on an EC pool
+    """Non-aligned overwrites on an EC pool
     round-trip — create, overwrite mid-object, extend past the end,
     write into a hole — all through the primary-coordinated RMW op."""
     c = cluster.client("rmw")

@@ -32,10 +32,8 @@ def test_full_domain_numpy(golden):
 def test_full_domain_jax(golden):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
     want = np.array(golden["ln"], dtype=np.uint64)
-    with enable_x64():
+    with jax.enable_x64(True):
         tables = (jnp.asarray(LN.RH_LH_NP), jnp.asarray(LN.LL_NP))
         got = jax.jit(lambda v: LN.crush_ln(v, xp=jnp, tables=tables))(
             jnp.arange(0x10000, dtype=jnp.uint32))

@@ -27,8 +27,12 @@ def load(name):
     return cmap, d
 
 
+@pytest.mark.parametrize("spec", ["1", "0"], ids=["spec", "general"])
 @pytest.mark.parametrize("name", MAP_FILES)
-def test_golden_map_batched(name):
+def test_golden_map_batched(name, spec, monkeypatch):
+    """Both lowerings: the speculative one takes every rule it accepts,
+    ``CEPH_TPU_SPEC_PIPELINE=0`` pins the general rule VM."""
+    monkeypatch.setenv("CEPH_TPU_SPEC_PIPELINE", spec)
     cmap, d = load(name)
     cargs = cmap.choose_args.get("golden")
     mapper = BatchedMapper(cmap, choose_args=cargs)
@@ -47,3 +51,28 @@ def test_golden_map_batched(name):
             got = list(res[i, :lens[i]])
             assert got == want, (name, ruleno, numrep, int(xs[i]),
                                  got, want)
+
+
+def test_launches_bounded_by_memory_budget(monkeypatch):
+    """The launch size comes from a small probe's footprint: a budget
+    that holds the whole batch maps it in one launch; a budget below
+    it splits the range into power-of-two launches (the tail padded)
+    with the same results."""
+    from ceph_tpu.crush import mapper_jax
+
+    monkeypatch.setattr(mapper_jax, "PROBE_LANES", 128)
+    cmap, d = load("map_tree3")
+    weight = np.asarray(d["cases"][0]["weight"], np.uint32)
+    xs = np.arange(1000, dtype=np.uint32)
+    whole = BatchedMapper(cmap)
+    want = [np.asarray(a) for a in whole.map_batch(0, xs, 3, weight)]
+    assert whole._lanes[(0, 3, 1000)] == 1000
+    per_lane = mapper_jax.footprint_bytes(whole._exe[(0, 3, 128)]) / 128
+    monkeypatch.setattr(mapper_jax, "launch_budget_bytes",
+                        lambda: int(per_lane * 300))
+    split = BatchedMapper(cmap)
+    got = [np.asarray(a) for a in split.map_batch(0, xs, 3, weight)]
+    assert split._lanes[(0, 3, 1000)] == 256
+    assert sorted(split._exe) == [(0, 3, 128), (0, 3, 256)]
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)

@@ -478,3 +478,37 @@ def test_concurrent_sends_coalesce_without_corruption():
     finally:
         client.shutdown()
         server.shutdown()
+
+
+def test_reply_slower_than_connect_timeout_keeps_the_connection(
+        monkeypatch):
+    """A reply that takes longer than the connect deadline arrives on
+    the same connection, and the op runs once: the deadline used to
+    stay on the socket as a read timeout, so the reader dropped the
+    session under every slow op and the resync re-sent it."""
+    import ceph_tpu.msg.messenger as M
+
+    monkeypatch.setattr(M, "_CONNECT_TIMEOUT", 0.3)
+    server, client = mk_pair()
+    calls = []
+
+    def slow(msg):
+        calls.append(msg["n"])
+        time.sleep(1.0)
+        return {"ok": True, "n": msg["n"]}
+
+    server.register("op", slow)
+    try:
+        assert client.call(server.addr, {"type": "op", "n": 0},
+                           timeout=10)["n"] == 0
+        with client._conn_lock:
+            sock = client._conns[tuple(server.addr)]
+        assert sock.gettimeout() is None
+        assert client.call(server.addr, {"type": "op", "n": 1},
+                           timeout=10)["n"] == 1
+        with client._conn_lock:
+            assert client._conns.get(tuple(server.addr)) is sock
+        assert calls == [0, 1]
+    finally:
+        client.shutdown()
+        server.shutdown()

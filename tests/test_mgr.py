@@ -192,7 +192,8 @@ def test_balancer_proposes_upmaps_and_pauses_degraded():
             return all(pgid in svc.map.pg_upmap_items
                        for svc in cl.osds.values())
         _wait(_osds_observed, 30, "OSD followers observing the upmap")
-        assert pgid in mgr.map.pg_upmap_items  # and the mgr itself
+        _wait(lambda: pgid in mgr.map.pg_upmap_items, 30,
+              "the mgr observing the upmap")  # and the mgr itself
         # the round logs its record after the LAST proposal commits,
         # while the monitor map shows the first one immediately
         _wait(lambda: bal.proposal_log, 30, "proposal round recorded")

@@ -6,7 +6,6 @@ import json
 import pathlib
 
 import numpy as np
-import pytest
 
 from ceph_tpu.crush import constants as C
 from ceph_tpu.crush.builder import (add_simple_rule, build_hierarchy,
@@ -18,10 +17,7 @@ from ceph_tpu.crush.builder import (add_simple_rule, build_hierarchy,
 from ceph_tpu.crush.map import (Bucket, ChooseArg, ChooseArgMap,
                                 CrushMap, Rule, RuleStep, Tunables)
 from ceph_tpu.crush.mapper_ref import crush_do_rule
-from ceph_tpu.crush.native import NativeMapper, available
-
-pytestmark = pytest.mark.skipif(
-    not available(), reason="native toolchain unavailable")
+from ceph_tpu.crush.native import NativeMapper
 
 GOLDEN = pathlib.Path(__file__).parent / "golden"
 

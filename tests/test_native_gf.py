@@ -3,12 +3,9 @@
 import numpy as np
 import pytest
 
-from ceph_tpu.ec.native_gf import NativeRS, available, gf8_matmul
+from ceph_tpu.ec.native_gf import NativeRS, gf8_matmul
 from ceph_tpu.ec import gf
 from ceph_tpu.ec.rs_jax import RSCode
-
-pytestmark = pytest.mark.skipif(
-    not available(), reason="native toolchain unavailable")
 
 
 def test_gf8_matmul_matches_reference():

@@ -1,6 +1,6 @@
 """Monitor quorum: election, replicated epochs, leader failover.
 
-The VERDICT round-3 acceptance test: a 3-monitor MiniCluster keeps
+The round-3 acceptance test: a 3-monitor MiniCluster keeps
 accepting writes after the leader is killed mid-workload, a restarted
 monitor rejoins and catches up, and committed epochs NEVER fork — every
 epoch present on two members is byte-identical.

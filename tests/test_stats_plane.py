@@ -251,9 +251,9 @@ def test_bench_slo_block_semantics():
 
 # -- satellite: perf_history trajectory -------------------------------------
 
-def test_perf_history_renders_repo_trajectory():
-    """The committed BENCH_r01..rNN series renders as a trajectory
-    table with per-metric deltas."""
+def test_perf_history_renders_bench_trajectory(tmp_path):
+    """A BENCH_r01..r05 series renders as a trajectory table with
+    per-metric deltas."""
     import pathlib
     import sys
 
@@ -261,9 +261,17 @@ def test_perf_history_renders_repo_trajectory():
                            .parent.parent))
     from tools import perf_history
 
-    repo = pathlib.Path(__file__).resolve().parent.parent
-    rows = perf_history.load_all(str(repo))
-    assert len(rows) >= 5, "BENCH_r*.json series missing"
+    for n in range(1, 6):
+        rate = 80_000.0 + 1_000.0 * n
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps({
+            "n": str(n), "rc": "0",
+            "parsed": {"metric": "crush_mappings_per_sec",
+                       "value": rate, "platform": "cpu",
+                       "vs_baseline": rate / 85099.6},
+            "tail": f"# ec k=8,m=3: encode 0.{n}0 GB/s, decode 0.50 "
+                    f"GB/s on cpu (compile 0.1s)\n"}))
+    rows = perf_history.load_all(str(tmp_path))
+    assert len(rows) == 5
     perf_history.compute_deltas(rows)
     by_run = {r["run"]: r for r in rows}
     # r05 recorded the measured trajectory numbers

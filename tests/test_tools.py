@@ -148,6 +148,30 @@ def test_tester_stats_scalar():
     assert int(rep2.device_stored[3]) < int(rep.device_stored[3])
 
 
+@pytest.mark.parametrize("engine", ["scalar", "batched", "native"])
+def test_tester_stats_match_loop_reference(engine):
+    """The array-derived statistics equal a per-mapping loop over the
+    same results; num_rep 3 on two hosts leaves every mapping short."""
+    t = CrushTester(compile_crushmap(SAMPLE))
+    t.set_device_weight(2, 0.5)
+    rep = t.test_rule(0, 3, 0, 511, scalar=engine == "scalar",
+                      native=engine == "native", collect_mappings=True)
+    n_dev = len(rep.device_stored)
+    stored = np.zeros(n_dev, np.int64)
+    sizes, bad = {}, []
+    for x, r in enumerate(rep.mappings):
+        sizes[len(r)] = sizes.get(len(r), 0) + 1
+        for o in r:
+            if 0 <= o < n_dev:
+                stored[o] += 1
+        if len(r) != 3:
+            bad.append((x, r))
+    assert rep.size_counts == sizes
+    assert np.array_equal(rep.device_stored, stored)
+    assert rep.bad == bad and len(bad) == 512
+    assert all(type(o) is int for r in rep.mappings for o in r)
+
+
 def test_tester_compare_detects_difference():
     w1 = compile_crushmap(SAMPLE)
     w2 = compile_crushmap(SAMPLE)
