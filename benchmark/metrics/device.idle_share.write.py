@@ -1,0 +1,8 @@
+"""Percent of the traced window in which no operation ran on the
+device."""
+
+from benchmark.lib.readers import idle_share
+
+
+def read(run):
+    return idle_share(run)
