@@ -1,0 +1,9 @@
+"""Percent of the summed time of the window's EC shard pushes (the
+primary's ``call:shard_write`` spans; ``benchmark/lib/pushes.py``) spent
+in the holder's dispatch queue (the handler span's ``q_wait``)."""
+
+from benchmark.lib.pushes import share
+
+
+def read(run):
+    return share(run, "queue")

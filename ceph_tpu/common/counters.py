@@ -96,6 +96,9 @@ REGISTRY: Dict[str, Dict[str, str]] = {
         # book none; a Pallas kernel in interpret mode books the next)
         "device_launches": U64,
         "interpret_launches": U64,
+        # host wall time of each dispatch: the kernel runs
+        # asynchronously and no wait on the device is timed, so these
+        # are not device time (a profiler trace gives that)
         "encode_time": TIME,
         "decode_time": TIME,
         "jit_compile_time": TIME,
@@ -113,6 +116,8 @@ REGISTRY: Dict[str, Dict[str, str]] = {
         "map_calls": U64,
         "xs_mapped": U64,
         "jit_compiles": U64,
+        # host wall time of each launch's dispatch, not device time:
+        # the program runs asynchronously and no wait is timed
         "map_time": TIME,
         "jit_compile_time": TIME,
         "map_lat": HIST,
@@ -199,6 +204,8 @@ REGISTRY: Dict[str, Dict[str, str]] = {
         "h2d_bytes": U64,
         "d2h_bytes": U64,
         "kernel_launches": U64,
+        # summed host dispatch wall time of the launches above, not
+        # device time: no launch waits for its kernel to finish
         "kernel_time": TIME,
         "live_buffers": GAUGE,
         "live_buffer_bytes": GAUGE,

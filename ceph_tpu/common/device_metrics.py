@@ -8,9 +8,9 @@ OS-level counters.  This module is the process-global accounting the
 jitted kernels (``ec.engine``, ``crush.mapper_jax``) book into:
 
 - ``device`` perf logger: h2d/d2h transfer bytes, kernel launch
-  count/time, live-buffer count/bytes gauges with a highwater mark
-  (the DaemonHealthMetrics role for the device plane).
-- a per-shape-signature table: wall time + transfer volume keyed by
+  count and host dispatch time, live-buffer count/bytes gauges with a
+  highwater mark (the DaemonHealthMetrics role for the device plane).
+- a per-shape-signature table: dispatch time + transfer volume keyed by
   ``<logger>|<signature>`` — the same shape key XLA's jit cache uses,
   so a new row appearing in steady state IS a recompile (the
   jaxcheck budget gate's observability twin).  Bounded; sampled into
@@ -59,7 +59,9 @@ def record_launch(logger: str, sig: object, seconds: float,
                   h2d_bytes: int = 0, d2h_bytes: int = 0) -> None:
     """Book one device-kernel launch: callers pass the bytes they
     moved host->device (inputs) and device->host (materialized
-    outputs) alongside the wall time."""
+    outputs) alongside the host wall time of the dispatch.  Launches
+    are asynchronous and no caller waits for the kernel, so
+    ``seconds`` is not device time; a profiler trace gives that."""
     _pc.inc("kernel_launches")
     _pc.tinc("kernel_time", seconds)
     if h2d_bytes:

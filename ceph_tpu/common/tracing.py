@@ -12,16 +12,25 @@ several processes' ring buffers, reassembled by trace_id with
 Model:
 
 - ``Span``: (trace_id, span_id, parent_id) + name/service/tags, wall
-  start time, monotonic duration, timestamped events (``log()``),
-  idempotent ``finish()``.  Spans are context managers and the
-  concurrency lint (CONC004) enforces that shape — a span that escapes
-  its ``with`` is exactly the leak the per-test span gate catches.
-- ``Tracer``: per-daemon factory + per-process ring buffer of finished
-  spans (bounded, newest-wins) + the sampling decision.  Sampling is
-  decided at the trace ROOT (probability ``sample_rate``) and
-  inherited by every child, local or remote, via the wire carrier —
-  an unsampled span still propagates its context (so downstream
-  daemons agree) but is never recorded.
+  start time, monotonic duration, idempotent ``finish()``.  A SAMPLED
+  span also stamps its start and end (``t0_ns``/``t1_ns``) and its
+  events (``log()``) on one clock, ``time.perf_counter_ns()``, and,
+  when jax is already imported, holds a ``jax.profiler.TraceAnnotation``
+  of its own name for the life of its ``with`` block, so a profile
+  shows the span on its host plane, on the clock of the device's ops.
+  An unsampled span does none of this.  Spans are context managers
+  and the concurrency lint (CONC004) enforces that shape — a span that
+  escapes its ``with`` is exactly the leak the per-test span gate
+  catches.
+- ``Tracer``: per-daemon factory + ring buffer of finished spans
+  (bounded, newest-wins, counting what it evicts) + the sampling
+  decision.  Sampling is decided at the trace ROOT (probability
+  ``sample_rate``) and inherited by every child, local or remote, via
+  the wire carrier — an unsampled span still propagates its context
+  (so downstream daemons agree) but is never recorded.
+- The flight recorder: each service name's newest ring stays listed
+  (``rings()``) after its daemon stops, until a tracer of the same
+  service starts — what a daemon did just before it stopped.
 - Thread-local parenting: a span opened while another span of the
   same tracer is active on this thread becomes its child
   automatically; cross-thread and cross-daemon parents pass
@@ -38,11 +47,12 @@ from __future__ import annotations
 import collections
 import itertools
 import random
+import sys
 import threading
 import time
 import uuid
 import weakref
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from ..analysis.lockdep import make_lock
 
@@ -50,6 +60,18 @@ from ..analysis.lockdep import make_lock
 # (tests/conftest.py) and debugging; weak so runtimes can die
 _tracers: "weakref.WeakSet" = weakref.WeakSet()
 _tracers_lock = make_lock("tracing::registry")
+
+
+class _Ring(collections.deque):
+    """A tracer's finished-span ring; ``evicted`` counts the spans the
+    bound pushed out."""
+
+    evicted = 0
+
+
+# service name -> the newest tracer's ring of that name; strong, so a
+# ring outlives its daemon (guarded by _tracers_lock)
+_rings: Dict[str, _Ring] = {}
 
 
 _id_prefix = uuid.uuid4().hex[:8]
@@ -64,6 +86,11 @@ def _gen_id() -> str:
 
 
 class Span:
+    # stamped only on sampled spans (perf_counter_ns)
+    t0_ns: Optional[int] = None
+    t1_ns: Optional[int] = None
+    _annotation = None
+
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str],
                  sampled: bool, tags: Optional[Dict] = None):
@@ -79,10 +106,13 @@ class Span:
         self._t0 = time.monotonic()
         self.duration: Optional[float] = None
         self.done: Optional[float] = None
+        if sampled:
+            self.t0_ns = time.perf_counter_ns()
 
     # -- recording ----------------------------------------------------
     def log(self, event: str) -> None:
-        self.events.append((time.time(), event))
+        if self.sampled:
+            self.events.append((time.perf_counter_ns(), event))
 
     def set_tag(self, key: str, value) -> None:
         self.tags[key] = value
@@ -94,16 +124,28 @@ class Span:
             return
         self.done = time.time()
         self.duration = time.monotonic() - self._t0
+        if self.sampled:
+            self.t1_ns = time.perf_counter_ns()
         self.tracer._finish(self)
 
     # -- context manager (the only lint-clean way to use a span) ------
     def __enter__(self) -> "Span":
         self.tracer._push(self)
+        if self.sampled:
+            # never imports jax: a process without it (a monitor) must
+            # not initialise a backend for a trace annotation
+            profiler = sys.modules.get("jax.profiler")
+            if profiler is not None:
+                self._annotation = profiler.TraceAnnotation(self.name)
+                self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, _tb) -> bool:
         if exc is not None:
             self.set_tag("error", repr(exc))
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         self.tracer._pop(self)
         self.finish()
         return False
@@ -115,9 +157,10 @@ class Span:
                 "duration": (self.duration
                              if self.duration is not None
                              else time.monotonic() - self._t0),
+                "t0_ns": self.t0_ns, "t1_ns": self.t1_ns,
                 "finished": self.done is not None,
                 "tags": dict(self.tags),
-                "events": [{"time": t, "event": e}
+                "events": [{"t_ns": t, "event": e}
                            for t, e in self.events]}
 
 
@@ -156,8 +199,7 @@ class Tracer:
                  sample_rate: float = 1.0):
         self.service = service
         self.sample_rate = sample_rate
-        self._ring: "collections.deque[Span]" = collections.deque(
-            maxlen=ring_size)
+        self._ring = _Ring(maxlen=ring_size)
         self._active: Dict[str, Span] = {}
         self._lock = make_lock("tracing::tracer")
         self._tls = threading.local()
@@ -166,6 +208,7 @@ class Tracer:
         self.sampled_out = 0  # finished but not recorded (sampling)
         with _tracers_lock:
             _tracers.add(self)
+            _rings[service] = self._ring
 
     # -- thread-local span stack --------------------------------------
     def current(self) -> Optional[Span]:
@@ -242,6 +285,8 @@ class Tracer:
             self._active.pop(span.span_id, None)
             self.finished += 1
             if span.sampled:
+                if len(self._ring) == self._ring.maxlen:
+                    self._ring.evicted += 1
                 self._ring.append(span)
             else:
                 self.sampled_out += 1
@@ -269,7 +314,8 @@ class Tracer:
                       if trace_id is None or s.trace_id == trace_id]
             counters = {"started": self.started,
                         "finished": self.finished,
-                        "sampled_out": self.sampled_out}
+                        "sampled_out": self.sampled_out,
+                        "evicted": self._ring.evicted}
         if limit:
             spans = spans[-int(limit):]
         return {"service": self.service,
@@ -315,3 +361,12 @@ def abandon_all_active() -> List[tuple]:
         tracers = list(_tracers)
     return [(t.service, s) for t in tracers
             for s in t.abandon_active()]
+
+
+def rings() -> List[Tuple[str, "collections.deque[Span]", int]]:
+    """(service, finished-span ring, spans it evicted) for the newest
+    tracer of each service name in the process — live rings, not
+    copies, kept after their daemon stops.  Copy one with ``list()``
+    before reading it while its daemon may still finish spans."""
+    with _tracers_lock:
+        return [(svc, ring, ring.evicted) for svc, ring in _rings.items()]

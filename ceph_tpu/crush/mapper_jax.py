@@ -63,8 +63,10 @@ from .map_arrays import MapArrays, MapStatic, encode_map  # noqa: E402
 
 # process-global batched-mapper metrics (served through every daemon's
 # `perf dump`, which merges the global collection): launch count/size,
-# steady-state latency, and first-call JIT compile count/time kept
-# SEPARATE so compile cost never pollutes the steady-state histogram
+# steady-state host dispatch time (not device time: launches are
+# asynchronous and not waited for), and first-call JIT compile
+# count/time kept SEPARATE so compile cost never pollutes the
+# steady-state histogram
 _pc = collection().create("crush.mapper")
 for _k in ("map_calls", "xs_mapped", "jit_compiles"):
     _pc.add_u64_counter(_k)
@@ -829,8 +831,9 @@ def book_map_batch(sig, dt: float, n_xs: int, result_max: int,
     """Shared perf/device-plane booking for one batched-mapper launch
     (the single-device ``BatchedMapper`` and the mesh-sharded
     ``parallel.PlacementPlane`` both land here, so `perf dump` and the
-    recompile-budget gate see ONE ``crush.mapper`` story).  First-call
-    compiles book separately from steady-state latency; mesh launches
+    recompile-budget gate see ONE ``crush.mapper`` story).  ``dt`` is
+    the host wall time of the dispatch, not device time.  First-call
+    compiles book separately from steady-state dispatch; mesh launches
     additionally book a per-device row for every participating chip."""
     _pc.inc("map_calls")
     _pc.inc("xs_mapped", n_xs)

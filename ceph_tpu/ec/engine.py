@@ -45,8 +45,10 @@ _BITS8 = np.arange(8, dtype=np.uint8)
 # every in-process daemon; served via each daemon's `perf dump`, which
 # merges the global collection).  First-call JIT compile cost books
 # under jit_compiles/jit_compile_time — keyed by kernel signature, the
-# same shape key XLA's own jit cache uses — so steady-state latency
-# histograms are not polluted by tracing+compilation.
+# same shape key XLA's own jit cache uses — so steady-state dispatch
+# histograms are not polluted by tracing+compilation.  The times are
+# host wall time of each dispatch, not device time: kernels run
+# asynchronously and nothing here waits for them.
 _pc = collection().create("ec.engine")
 for _k in ("encode_ops", "decode_ops", "encode_bytes",
            "decode_bytes", "jit_compiles", "device_launches",
@@ -97,7 +99,9 @@ def _account(kind: str, sig: tuple, dt: float, nbytes: int,
              device_ids=None, interpret: bool = False) -> None:
     """Shared by every EC execution engine (the jitted bit-plane path
     here and native_gf's table engine, which passes jitted=False —
-    it has no compile step to separate out).  Jitted launches also
+    it has no compile step to separate out).  ``dt`` is the host wall
+    time of the call: for a jitted engine, the dispatch, since the
+    kernel runs asynchronously and is not waited for.  Jitted launches also
     book into the device plane: the input bytes cross host->device,
     the materialized output crosses back (common/device_metrics.py,
     per-shape-signature).  Mesh launches pass ``device_ids`` so every

@@ -70,7 +70,7 @@ from ..common.backoff import Backoff
 from ..common.encoding import MalformedInput
 from ..common.log import getLogger
 from ..common.perf_counters import PerfCounters
-from ..common.tracing import Tracer
+from ..common.tracing import Span, Tracer
 
 Addr = Tuple[str, int]
 Handler = Callable[[Dict], Optional[Dict]]
@@ -90,12 +90,13 @@ Handler = Callable[[Dict], Optional[Dict]]
 
 
 class _SendOp:
-    __slots__ = ("buf", "done", "error")
+    __slots__ = ("buf", "done", "error", "on_write")
 
-    def __init__(self, buf: bytes):
+    def __init__(self, buf: bytes, on_write=None):
         self.buf = buf
         self.done = threading.Event()
         self.error: Optional[OSError] = None
+        self.on_write = on_write
 
 
 class _SockWriter:
@@ -428,7 +429,7 @@ def _sendmsg_all(sock: socket.socket, parts: list) -> None:
 
 
 def _send_frame(sock: socket.socket, msg: Dict, keyring=None,
-                mutate=None) -> Tuple[int, int]:
+                mutate=None, on_write=None) -> Tuple[int, int]:
     """Queue the frame on the socket's writer and flush — coalescing
     with whatever else is queued — as the writer-lock holder.  Returns
     ``(wire_size, joined)``: the wire size (header + payload) for the
@@ -441,7 +442,11 @@ def _send_frame(sock: socket.socket, msg: Dict, keyring=None,
     ``mutate`` (fault injection only) post-processes the framed bytes
     — flipping or truncating them — INSIDE the writer path, so the
     damaged frame still serializes correctly against coalesced
-    writers instead of interleaving mid-batch."""
+    writers instead of interleaving mid-batch.
+
+    ``on_write`` (when set) is called, on whichever thread writes the
+    frame, as its encoded bytes are handed to the socket: after the
+    encode and any wait for the writer, before the write itself."""
     parts, plen = encode_frame_parts(msg, keyring)
     parts.insert(0, struct.pack(">I", plen))
     buf = None
@@ -460,6 +465,8 @@ def _send_frame(sock: socket.socket, msg: Dict, keyring=None,
         try:
             if not w.q:
                 fast = True
+                if on_write is not None:
+                    on_write()
                 if buf is not None:
                     sock.sendall(buf)
                 else:
@@ -475,7 +482,7 @@ def _send_frame(sock: socket.socket, msg: Dict, keyring=None,
     # batch it with its queue neighbours in one send
     if buf is None:
         buf = b"".join(parts)
-    op = _SendOp(buf)
+    op = _SendOp(buf, on_write)
     w.q.append(op)  # deque.append is atomic; order = send order
     while not op.done.is_set():
         if not w.lock.acquire(timeout=0.05):
@@ -491,6 +498,9 @@ def _send_frame(sock: socket.socket, msg: Dict, keyring=None,
                 if not batch:
                     break
                 err: Optional[OSError] = None
+                for o in batch:
+                    if o.on_write is not None:
+                        o.on_write()
                 try:
                     # ONE gathered send for the whole batch (the
                     # writev role): the dominant cost of small frames
@@ -914,10 +924,12 @@ class Messenger:
                                              _ConnStats(peer))
         return cs
 
-    def _send(self, conn: socket.socket, msg: Dict) -> None:
+    def _send(self, conn: socket.socket, msg: Dict,
+              on_write=None) -> None:
         """Sign-at-wire-time send: frames are stored/buffered unsigned
         (and may hold raw ``bytes`` values); the MAC is computed over
-        the lifted control segment + data-segment digests."""
+        the lifted control segment + data-segment digests.
+        ``on_write``: see ``_send_frame``."""
         # stall clock starts BEFORE the fault block: an armed
         # msgr.delay_frame models a slow wire, and the whole point of
         # the meter is that slow wires surface as send stall
@@ -941,7 +953,7 @@ class Messenger:
         w = _sock_writers.get(id(conn))
         depth = len(w.q) if w is not None else 0
         n, joined = _send_frame(conn, msg, self.keyring,
-                                mutate=mutate)
+                                mutate=mutate, on_write=on_write)
         self.pc.inc("bytes_out", n)
         self.pc.inc("frames_out")
         cs = self._conn_stat(conn)
@@ -986,7 +998,8 @@ class Messenger:
         drop-bad-frame log."""
         owned = seg
         try:
-            t_rx = time.monotonic()  # dispatch_lat anchor: receipt
+            # dispatch_lat anchor: receipt, on the clock of the spans
+            t_rx = time.perf_counter()
             if self.keyring is not None and \
                     not self.keyring.verify(msg, blobs):
                 return  # unauthenticated frame: drop (cephx deny)
@@ -1195,6 +1208,14 @@ class Messenger:
             if handler is None:
                 reply = {"error": f"no handler for {type_!r}"}
             else:
+                # frame receipt -> handler start: the dispatch queue
+                # wait, split into its own attribution stage
+                # (common/attribution.py) AND the per-lane wait
+                # histogram (the DispatchQueue saturation signal
+                # dump_messenger reads).  Read before the span opens,
+                # so span start - q_wait never precedes the receipt.
+                q_wait = None if t_rx is None else \
+                    time.perf_counter() - t_rx
                 # child span of the sender's call/send span when the
                 # frame carries trace context (the server half of the
                 # rpc); the no-op span otherwise, so untraced traffic
@@ -1202,16 +1223,9 @@ class Messenger:
                 with self.tracer.start_span(
                         f"handle:{type_}",
                         child_of=msg.get("trace"),
-                        require_parent=True,
-                        tags={"frm": msg.get("frm", "")}) as sp:
-                    if t_rx is not None:
-                        # frame receipt -> handler start: the dispatch
-                        # queue wait, split into its own attribution
-                        # stage (common/attribution.py) AND the
-                        # per-lane wait histogram (the DispatchQueue
-                        # saturation signal dump_messenger reads)
-                        q_wait = time.monotonic() - t_rx
-                        sp.set_tag("q_wait", round(q_wait, 6))
+                        require_parent=True) as sp:
+                    if q_wait is not None:
+                        sp.set_tag("q_wait", q_wait)
                         cs = self._conn_stat(conn)
                         if ctl:
                             self.pc.hist_add("dispatch_wait_ctl",
@@ -1288,7 +1302,7 @@ class Messenger:
                 except OSError:
                     pass
         if t_rx is not None:
-            dt = time.monotonic() - t_rx
+            dt = time.perf_counter() - t_rx
             self.pc.hist_add("dispatch_lat", dt)
             self.pc.tinc("dispatch_time", dt)
             cs = self._conn_stat(conn)
@@ -1486,8 +1500,10 @@ class Messenger:
         sess.synced = True
 
     def _send_sequenced(self, addr: Addr, msg: Dict,
-                        timeout: float = 5.0) -> int:
+                        timeout: float = 5.0, on_write=None) -> int:
         """Returns the assigned seq (call() completes it on reply).
+        ``on_write``: see ``_send_frame``; a frame that goes out in a
+        session replay calls it once the replay has written it.
 
         Bounded end to end by ``timeout``: the session lock may be
         held for seconds by a background resync handshaking with a
@@ -1518,10 +1534,12 @@ class Messenger:
             sess.buffer(seq, frame, needs_reply)
             try:
                 if sess.synced:
-                    self._send(self._connect(addr), frame)
+                    self._send(self._connect(addr), frame, on_write)
                 else:
                     self._ensure_synced(addr, deadline)  # replays
                     # every buffered frame, this one included
+                    if on_write is not None:
+                        on_write()
             except (OSError, TimeoutError):
                 # one immediate retry on a fresh connection; further
                 # healing happens in the background resync
@@ -1529,6 +1547,8 @@ class Messenger:
                 sess.synced = False
                 try:
                     self._ensure_synced(addr, deadline)
+                    if on_write is not None:
+                        on_write()
                 except (OSError, TimeoutError):
                     if msg.get("tid") is not None:
                         # the call is failing to its caller: a frame
@@ -1546,8 +1566,8 @@ class Messenger:
         is being traced on this thread the frame carries the span
         context (no-op span — and no wire field — otherwise)."""
         with self.tracer.start_span(
-                f"send:{msg.get('type', '?')}", require_parent=True,
-                tags={"peer": f"{addr[0]}:{addr[1]}"}) as sp:
+                f"send:{msg.get('type', '?')}",
+                require_parent=True) as sp:
             carrier = self.tracer.inject(sp)
             if carrier is not None:
                 msg = dict(msg, trace=carrier)
@@ -1578,18 +1598,22 @@ class Messenger:
         Tracing: every call gets a span (a child of this thread's
         active span when one exists, else a new root) and the frame
         carries its context, so the peer's handler span joins the
-        same trace."""
+        same trace.  A sampled span logs ``sent`` as the request frame
+        is handed to the socket: the session lock, the frame encode and
+        any wait for the socket's writer lie before it; the socket
+        write, the peer's reader, dispatch queue and handler, and the
+        reply after."""
         with self.tracer.start_span(
-                f"call:{msg.get('type', '?')}",
-                tags={"peer": f"{addr[0]}:{addr[1]}"}) as sp:
+                f"call:{msg.get('type', '?')}") as sp:
             carrier = self.tracer.inject(sp)
             if carrier is not None:
                 msg = dict(msg, trace=carrier)
-            return self._call(addr, msg, timeout)
+            return self._call(addr, msg, timeout, sp)
 
-    def _call(self, addr: Addr, msg: Dict,
-              timeout: float = 10.0) -> Dict:
+    def _call(self, addr: Addr, msg: Dict, timeout: float,
+              span: Span) -> Dict:
         tid = _next_tid()
+        on_write = (lambda: span.log("sent")) if span.sampled else None
         deadline = time.monotonic() + timeout
         seq = None
         sock = None
@@ -1602,18 +1626,19 @@ class Messenger:
                 with sess.buf_lock:
                     sess.waiters.add(tid)
                 seq = self._send_sequenced(addr, dict(msg, tid=tid),
-                                           timeout=timeout)
+                                           timeout=timeout,
+                                           on_write=on_write)
             else:
                 smsg = dict(msg, tid=tid, frm=self.name)
                 try:
                     sock = self._connect(addr)
-                    self._send(sock, smsg)
+                    self._send(sock, smsg, on_write)
                 except OSError:
                     # stale cached connection (peer restarted): one
                     # fresh reconnect before giving up
                     self._drop(addr)
                     sock = self._connect(addr)
-                    self._send(sock, smsg)
+                    self._send(sock, smsg, on_write)
                 # lossy: no replay behind this call — it dies with
                 # its connection instead of waiting out the timeout
                 self._bind_waiter(sock, tid)

@@ -499,8 +499,7 @@ class OSDService(MapFollower):
                 # the WAL stage: queue_transaction through the
                 # group-commit fsync ack (attribution stage "wal")
                 with self.tracer.start_span(
-                        "store.commit", require_parent=True,
-                        tags={"bytes": len(data)}):
+                        "store.commit", require_parent=True):
                     self.store.queue_transaction(txn)
             op.mark_event("commit")
             if faults._ACTIVE and faults.fires(
@@ -857,9 +856,8 @@ class OSDService(MapFollower):
             # traced as a child of handle:ec_write when the client op
             # carries trace context — the per-stage latency the EC
             # characterization literature needs visible
-            with self.tracer.start_span(
-                    "ec.encode", require_parent=True,
-                    tags={"bytes": len(buf), "k": k, "m": n - k}):
+            with self.tracer.start_span("ec.encode",
+                                        require_parent=True):
                 # through the coalescer: concurrent writes to other
                 # PGs of this pool share one batched dispatch
                 chunks = self._ec_batcher.encode(code, range(n), buf)

@@ -2,7 +2,9 @@
 observability satellites: log2 latency histograms, strict perf-counter
 type checks, idempotent TrackedOp.finish."""
 
+import gc
 import threading
+import time
 
 import pytest
 
@@ -10,6 +12,7 @@ from ceph_tpu.common import tracing
 from ceph_tpu.common.op_tracker import OpTracker
 from ceph_tpu.common.perf_counters import PerfCounters
 from ceph_tpu.common.tracing import NOOP_SPAN, Tracer
+from ceph_tpu.msg.messenger import Messenger
 
 
 # -- spans ------------------------------------------------------------------
@@ -150,6 +153,118 @@ def test_active_spans_and_abandon():
     assert not any(s is sp for _svc, s in tracing.active_spans())
     # a later finish of an abandoned span must not blow up
     sp.finish()
+
+
+def _record_annotations(monkeypatch):
+    """Replace jax's TraceAnnotation with a recorder of opens/closes."""
+    import jax.profiler
+
+    log = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return log
+
+
+def test_unsampled_span_does_no_new_work(monkeypatch):
+    annotations = _record_annotations(monkeypatch)
+    t = Tracer("svc", sample_rate=0.0)
+    with t.start_span("op") as sp:
+        sp.log("ignored")
+    assert not sp.sampled
+    assert sp.t0_ns is None and sp.t1_ns is None and sp.events == []
+    assert annotations == []
+    assert t.dump()["spans"] == [] and t._ring.evicted == 0
+
+
+def test_sampled_span_stamps_one_ns_clock_and_annotates(monkeypatch):
+    annotations = _record_annotations(monkeypatch)
+    t = Tracer("svc")
+    before = time.perf_counter_ns()
+    with t.start_span("op") as sp:
+        assert annotations == [("open", "op")]
+        sp.log("mid")
+    after = time.perf_counter_ns()
+    assert annotations == [("open", "op"), ("close", "op")]
+    assert before <= sp.t0_ns <= sp.events[0][0] <= sp.t1_ns <= after
+    (d,) = t.dump()["spans"]
+    assert (d["t0_ns"], d["t1_ns"]) == (sp.t0_ns, sp.t1_ns)
+    assert d["events"] == [{"t_ns": sp.events[0][0], "event": "mid"}]
+
+
+def test_ring_outlives_its_tracer_until_the_service_restarts():
+    def listed():
+        return {svc: (ring, ev) for svc, ring, ev in tracing.rings()}
+
+    t = Tracer("flight-recorder", ring_size=2)
+    for i in range(5):
+        with t.start_span(f"op{i}"):
+            pass
+    assert t.dump()["evicted"] == 3
+    del t
+    gc.collect()
+    ring, evicted = listed()["flight-recorder"]
+    assert [s.name for s in ring] == ["op3", "op4"] and evicted == 3
+    t2 = Tracer("flight-recorder")
+    ring2, evicted2 = listed()["flight-recorder"]
+    assert ring2 is t2._ring and ring2 is not ring and evicted2 == 0
+
+
+def test_call_marks_sent_between_its_start_and_the_peers_receipt():
+    server = Messenger("trace-server", lossless=True,
+                       tracer=Tracer("trace-server"))
+    client = Messenger("trace-client", lossless=True,
+                       tracer=Tracer("trace-client"))
+    server.start()
+    client.start()
+    try:
+        server.register("op", lambda m: {"ok": True})
+        # the session's first frame goes out in its set-up replay, and
+        # logs ``sent`` only after it
+        assert client.call(server.addr, {"type": "op"})["ok"]
+        first = {s["span_id"] for s in client.tracer.dump()["spans"]}
+        for _ in range(3):
+            assert client.call(server.addr, {"type": "op"})["ok"]
+    finally:
+        client.shutdown()
+        server.shutdown()
+    calls = [s for s in client.tracer.dump()["spans"]
+             if s["name"] == "call:op" and s["span_id"] not in first]
+    handlers = {s["parent_id"]: s for s in server.tracer.dump()["spans"]
+                if s["name"] == "handle:op"}
+    assert len(calls) == 3
+    for c in calls:
+        h = handlers[c["span_id"]]
+        (sent,) = [e["t_ns"] for e in c["events"] if e["event"] == "sent"]
+        receipt = h["t0_ns"] - h["tags"]["q_wait"] * 1e9
+        assert c["t0_ns"] <= sent <= receipt <= h["t1_ns"] <= c["t1_ns"]
+
+
+def test_sampled_span_lies_on_the_host_plane_of_a_profile(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with Tracer("svc").start_span("probe.sampled"):
+            pass
+        with Tracer("svc", sample_rate=0.0).start_span("probe.unsampled"):
+            pass
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = {ev.name
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events}
+    assert "probe.sampled" in names
+    assert "probe.unsampled" not in names
 
 
 # -- perf-counter satellites -------------------------------------------------
