@@ -121,6 +121,10 @@ REGISTRY: Dict[str, Dict[str, str]] = {
         "map_time": TIME,
         "jit_compile_time": TIME,
         "map_lat": HIST,
+        # the speculative straggler pass: PGs re-run with the full
+        # retry loops, and the chunks of N/64 lanes that ran them
+        "spec_rerun_pgs": U64,
+        "spec_rerun_chunks": U64,
     },
     "crush.scalar": {
         "pg_lookups": U64,

@@ -10,7 +10,7 @@ every collection (perf_counters.h:63-141 / PerfCountersCollection).
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ..analysis.lockdep import make_lock
 
@@ -29,6 +29,7 @@ class PerfCounters:
         self._avgs: Dict[str, Tuple[int, float]] = {}
         self._hists: Dict[str, List[int]] = {}
         self._hist_mins: Dict[str, float] = {}
+        self._before_dump: List[Callable[[], None]] = []
         self._lock = make_lock("perf::counters")
 
     def _require(self, key: str, *allowed: str) -> str:
@@ -111,7 +112,14 @@ class PerfCounters:
             hist[bucket] += 1
 
     # -- dump ---------------------------------------------------------
+    def before_dump(self, fn: Callable[[], None]) -> None:
+        """Run ``fn`` before every dump: it books counts that are not
+        on the host yet (a device launch's)."""
+        self._before_dump.append(fn)
+
     def dump(self) -> Dict:
+        for fn in self._before_dump:
+            fn()
         with self._lock:
             out: Dict = {}
             for key, t in self._types.items():

@@ -32,6 +32,7 @@ descents.  The reformulation:
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
 import time
@@ -54,6 +55,7 @@ from jax import lax  # noqa: E402
 
 from . import constants as C  # noqa: E402
 from . import hash as H  # noqa: E402
+from ..analysis.lockdep import make_lock  # noqa: E402
 from ..common import device_metrics  # noqa: E402
 from ..common.perf_counters import collection  # noqa: E402
 from .ln import (LL_NP, RH_LH_NP, ln16_table, recip64,  # noqa: E402
@@ -73,6 +75,36 @@ for _k in ("map_calls", "xs_mapped", "jit_compiles"):
 _pc.add_time("map_time")
 _pc.add_time("jit_compile_time")
 _pc.add_histogram("map_lat")
+# the speculative straggler pass (mapper_spec.map_stragglers): PGs the
+# first round left unfinished, and the chunks of N/64 lanes that re-ran
+# them with the full retry loops
+for _k in ("spec_rerun_pgs", "spec_rerun_chunks"):
+    _pc.add_u64_counter(_k)
+_rerun_lock = make_lock("crush::reruns")
+_rerun_pending = collections.deque()   # stats i32[2] still on the device
+
+
+def defer_rerun_stats(stats) -> None:
+    """Keep a launch's straggler stats (``map_stragglers``' device
+    array) to book once they are read: the launch is not waited for."""
+    with _rerun_lock:
+        _rerun_pending.append(stats)
+
+
+def book_rerun_stats(wait: bool = False) -> None:
+    """Book the kept stats of launches that have finished; with
+    ``wait``, of all of them (``perf dump`` waits)."""
+    with _rerun_lock:
+        ready = []
+        while _rerun_pending and (wait or _rerun_pending[0].is_ready()):
+            ready.append(_rerun_pending.popleft())
+    for stats in ready:
+        pgs, chunks = (int(v) for v in np.asarray(stats))  # jax-ok: finished launches only, unless a dump waits
+        _pc.inc("spec_rerun_pgs", pgs)
+        _pc.inc("spec_rerun_chunks", chunks)
+
+
+_pc.before_dump(lambda: book_rerun_stats(wait=True))
 
 I32 = jnp.int32
 U32 = jnp.uint32

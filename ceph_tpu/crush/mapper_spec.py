@@ -25,6 +25,10 @@ speculative program instead:
   descent per outer try.
 - The per-rep round loop remains a ``lax.while_loop``, but its body now
   retires K tries per iteration and virtually always exits after one.
+- "Virtually always" is not "always": under ``vmap`` the loop runs every
+  lane until the slowest is done.  :func:`map_stragglers` therefore runs
+  one round over the whole batch and the loops only over the lanes that
+  need another round, compacted into chunks a fraction of its size.
 
 Bit-exactness contract: identical (result, len) to ``mapper_ref.py`` /
 ``mapper_jax.py`` for every eligible (map, rule, tunables) combination —
@@ -233,11 +237,14 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
                      choose_args: Optional[ChooseArgMap] = None,
                      encoded=None, k_tries: int = 8):
     """The unjitted single-x speculative program:
-    ``single(arrays, weight, x) -> (result i32[R], len i32)``.
+    ``single(arrays, weight, x) -> (result i32[R], len i32)``, and its
+    one-round variant ``one_round(arrays, weight, x) -> (result, len,
+    more bool)``: every retry loop runs its first round only, and
+    ``more`` says some loop would have run another.  Where ``more`` is
+    false the variant's answer is ``single``'s.
 
     Raises :class:`Ineligible` when the rule needs the general mapper.
-    Returns ``(single, static, arrays_np)`` like
-    ``mapper_jax.make_single_fn``.
+    Returns ``(single, one_round, static, arrays_np)``.
     """
     plan = analyze(cmap, ruleno, result_max)
     static, arrays_np = encoded if encoded is not None \
@@ -255,6 +262,20 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
     K = max(1, min(k_tries, plan.tries))
     maxdev = static.max_devices
     U64MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
+
+    def rounds(cond, body, st, one_round, x):
+        """A retry loop: ``lax.while_loop`` in the full program; in the
+        one-round variant the body once (the first round always runs),
+        and whether the loop would go on."""
+        if one_round:
+            st = body(st)
+            return st, cond(st)
+        # every carry starts as a value of x's lane: vmap then batches
+        # the loop in one pass of its body, not in one more per carry
+        # it finds per-lane (over a third of the program's trace time)
+        lane = x == x
+        st = jax.tree_util.tree_map(lambda v: jnp.where(lane, v, v), st)
+        return lax.while_loop(cond, body, st), jnp.bool_(False)
 
     def straw2_k(A, rw, x, cur, r, pos):
         """straw2 choose (mapper.c:287-362) over a (K,) vector of bucket
@@ -350,7 +371,7 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
                     | is_out(weight, dev, x))
         return jnp.where(bad, _FAIL, st), dev
 
-    def single_indep(A, weight, x, rw):
+    def single_indep(A, weight, x, rw, one_round):
         """crush_choose_indep (mapper.c:633-821) as dense rounds: the
         breadth-first structure is already a batch — every open slot's
         descent vectorizes, with a sequential unrolled commit pass that
@@ -422,15 +443,16 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
             return ftotal + 1, left, out, out2
 
         st = (jnp.int32(0), jnp.int32(NR), out, out2)
-        _, _, out, out2 = lax.while_loop(round_cond, round_body, st)
+        (_, _, out, out2), more = rounds(round_cond, round_body, st,
+                                         one_round, x)
         result = out2 if plan.leafy else out
         idx = jnp.arange(R, dtype=I32)
         result = jnp.where(idx < NR,
                            jnp.where(result == UNDEF, NONE, result),
                            NONE)
-        return result, jnp.int32(NR)
+        return result, jnp.int32(NR), more
 
-    def single(A, weight, x):
+    def program(A, weight, x, one_round):
         # weight reciprocals: unbatched under vmap (depend only on A), so
         # they are computed once per launch, not per lane
         rw = None
@@ -438,7 +460,8 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
             rw = recip64(A.arg_weights, xp=jnp) if static.has_choose_args \
                 else recip64(A.weights, xp=jnp)
         if not plan.firstn:
-            return single_indep(A, weight, x, rw)
+            return single_indep(A, weight, x, rw, one_round)
+        more = jnp.bool_(False)
         out = jnp.full(R, NONE, I32)
         out2 = jnp.full(R, NONE, I32)
         outpos = jnp.int32(0)
@@ -493,8 +516,9 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
 
             st = (jnp.int32(0), jnp.bool_(False), jnp.bool_(False),
                   jnp.int32(0), jnp.int32(0))
-            _, _, succ, host, dev = lax.while_loop(round_cond, round_body,
-                                                   st)
+            (_, _, succ, host, dev), rep_more = rounds(
+                round_cond, round_body, st, one_round, x)
+            more = more | rep_more
             slot = jnp.clip(outpos, 0, R - 1)
             out = jnp.where(succ, out.at[slot].set(host), out)
             out2 = jnp.where(succ, out2.at[slot].set(dev), out2)
@@ -503,20 +527,85 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
         result = out2 if plan.leafy else out
         idx = jnp.arange(R, dtype=I32)
         result = jnp.where(idx < outpos, result, NONE)
-        return result, outpos
+        return result, outpos, more
 
-    return single, static, arrays_np
+    def single(A, weight, x):
+        return program(A, weight, x, False)[:2]
+
+    def one_round(A, weight, x):
+        return program(A, weight, x, True)
+
+    return single, one_round, static, arrays_np
+
+
+# stragglers re-run in chunks of N // STRAGGLER_CHUNKS lanes; batches
+# under STRAGGLER_MIN_LANES run the plain loops, since chunks under 64
+# lanes save little and the second copy of the program doubles the
+# compile
+STRAGGLER_CHUNKS = 64
+STRAGGLER_MIN_LANES = 64 * STRAGGLER_CHUNKS
+
+
+def map_stragglers(single, one_round, A, weight, xs):
+    """Map a batch ``xs`` (u32[N]) with the speculative program, for one
+    round of retries per lane and then the full retry loops on the
+    stragglers alone.
+
+    Under ``vmap`` a retry ``while_loop`` runs every lane for as many
+    rounds as the slowest lane needs.  Here every lane runs
+    ``one_round``; the lanes it flags are gathered, in order, into
+    chunks of ``N // STRAGGLER_CHUNKS`` lanes, ``single`` re-runs each
+    chunk with its loops (for as many rounds as that chunk's slowest
+    lane needs), and the answers are scattered back.  One chunk size
+    keeps one copy of ``single`` in the program; any count of
+    stragglers, up to all ``N``, stays exact.
+
+    Returns ``(result i32[N,R], len i32[N], stats i32[2])``, ``stats``
+    being the stragglers and the chunks re-run, or None where ``N`` is
+    under :data:`STRAGGLER_MIN_LANES` and the plain loops ran.
+    """
+    n = xs.shape[0]
+    full = jax.vmap(single, in_axes=(None, None, 0))
+    if n < STRAGGLER_MIN_LANES:
+        return full(A, weight, xs) + (None,)
+    cap = n // STRAGGLER_CHUNKS
+    res, lens, more = jax.vmap(one_round, in_axes=(None, None, 0))(
+        A, weight, xs)
+    flagged = jnp.sum(more, dtype=I32)
+    # the stragglers' lanes first, in order (a stable sort: the TPU
+    # compiler cannot fit jnp.nonzero's cumsum over 2^18 lanes)
+    order = jnp.argsort(~more, stable=True).astype(I32)
+    order = jnp.pad(order, (0, -n % cap))
+
+    def chunk(st):
+        c, res, lens = st
+        lane = lax.dynamic_slice(order, (c * cap,), (cap,))
+        pad = c * cap + jnp.arange(cap, dtype=I32) >= flagged
+        # pad lanes repeat the chunk's first straggler, so they add no
+        # round, and write nothing back
+        r, ln = full(A, weight, xs[jnp.where(pad, lane[0], lane)])
+        idx = jnp.where(pad, n, lane)
+        return (c + 1, res.at[idx].set(r, mode="drop"),
+                lens.at[idx].set(ln, mode="drop"))
+
+    chunks, res, lens = lax.while_loop(
+        lambda st: st[0] * cap < flagged, chunk, (jnp.int32(0), res, lens))
+    return res, lens, jnp.stack([flagged, chunks])
 
 
 def build_spec_rule_fn(cmap: CrushMap, ruleno: int, result_max: int,
                        choose_args: Optional[ChooseArgMap] = None,
                        encoded=None, k_tries: int = 8):
     """Compile one eligible rule into a jitted batched speculative mapper
-    with the same signature as ``mapper_jax.build_rule_fn``."""
-    single, static, arrays_np = make_single_spec(
+    (:func:`map_stragglers`) with the same signature as
+    ``mapper_jax.build_rule_fn``."""
+    single, one_round, static, arrays_np = make_single_spec(
         cmap, ruleno, result_max, choose_args, encoded, k_tries)
-    batched = jax.jit(jax.vmap(single, in_axes=(None, None, 0)))
-    return batched, static, arrays_np
+
+    def map_batch(A, weight, xs):
+        return map_stragglers(single, one_round, A, weight, xs)[:2]
+
+    return jax.jit(map_batch), static, arrays_np
 
 
 class SpeculativeMapper:

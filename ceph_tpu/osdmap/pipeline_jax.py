@@ -5,7 +5,9 @@ _pg_to_up_acting_osds): pps seed → CRUSH → nonexistent-filter → upmap →
 up-filter → primary affinity → pg_temp overlay.  The reference runs this
 per-PG on CPU and batches with a thread pool (ParallelPGMapper,
 src/osd/OSDMapMapping.h:18); here the PG axis is the vmapped batch axis
-and shards across the TPU mesh.
+and shards across the TPU mesh.  Unsharded, a speculative rule's CRUSH
+step runs one retry round over every PG and its full retry loops over
+the PGs that need more (``mapper_spec.map_stragglers``).
 
 Exception tables (pg_upmap/pg_upmap_items/pg_temp/primary_temp) are
 lowered host-side to dense per-PG arrays; stages that no PG uses are
@@ -28,7 +30,10 @@ import jax.numpy as jnp
 
 from ..crush import hash as H
 from ..crush.constants import CRUSH_ITEM_NONE as NONE
-from ..crush.mapper_jax import make_single_fn
+from ..crush.mapper_jax import (book_rerun_stats, defer_rerun_stats,
+                                make_single_fn)
+from ..crush.mapper_spec import (Ineligible, make_single_spec,
+                                 map_stragglers)
 from .osdmap import (DEFAULT_PRIMARY_AFFINITY, FLAG_HASHPSPOOL,
                      MAX_PRIMARY_AFFINITY, OSD_EXISTS, OSD_UP, OSDMap,
                      PgPool)
@@ -161,13 +166,10 @@ class PoolMapper:
             # CEPH_TPU_SPEC_PIPELINE=0 forces the general mapper.
             import os as _os
 
-            single = None
+            single = one_round = None
             if _os.environ.get("CEPH_TPU_SPEC_PIPELINE", "1") != "0":
-                from ..crush.mapper_spec import (Ineligible,
-                                                 make_single_spec)
-
                 try:
-                    single, static, arrays = make_single_spec(
+                    single, one_round, static, arrays = make_single_spec(
                         m.crush, pool.crush_rule, R,
                         choose_args=cargs, k_tries=1)
                 except Ineligible:
@@ -177,8 +179,12 @@ class PoolMapper:
                     m.crush, pool.crush_rule, R, choose_args=cargs)
             self.arrays = jax.tree_util.tree_map(jnp.asarray, arrays)
         else:
-            single = None
+            single = one_round = None
             self.arrays = None
+        # the straggler pass gathers across the PG axis, which a mesh
+        # shards: meshed mappers keep the plain vmapped loops
+        if mesh is not None:
+            one_round = None
 
         tabs = _lower_tables(m, pool_id, pool)
         self.tabs = tabs
@@ -203,14 +209,7 @@ class PoolMapper:
             upb = inr & ((st & OSD_UP) != 0)
             return ex, upb
 
-        def single_pg(A, weight, state, paff, trow, ps):
-            pps = seed(ps)
-            if single is not None:
-                raw, rlen = single(A, weight, pps)
-            else:
-                raw = jnp.full(R, NONE, I32)
-                rlen = jnp.int32(0)
-
+        def after_crush(weight, state, paff, trow, pps, raw, rlen):
             # _remove_nonexistent_osds (OSDMap.cc:2408)
             ex, upb = osd_ok(raw, state)
             if shift:
@@ -313,7 +312,7 @@ class PoolMapper:
 
             return (up, ulen2, up_primary, acting, alen, acting_primary)
 
-        # vmapped over ps + per-pg table rows
+        # per-pg table rows
         self._trow = {}
         if tabs.upmap is not None:
             self._trow["upmap"] = jnp.asarray(tabs.upmap)
@@ -327,11 +326,29 @@ class PoolMapper:
         if tabs.ptemp is not None:
             self._trow["ptemp"] = jnp.asarray(tabs.ptemp)
         trow_axes = {k: 0 for k in self._trow}
+        lanes = jax.vmap(after_crush,
+                         in_axes=(None, None, None, trow_axes, 0, 0, 0))
 
-        vmapped = jax.vmap(
-            single_pg, in_axes=(None, None, None, None, trow_axes, 0))
+        def single_pg(A, weight, state, paff, trow, ps):
+            """Every PG of the batch ``ps``: CRUSH, then the OSDMap
+            stages.  Where the speculative rule maps through the
+            straggler pass, its stats are a seventh output."""
+            pps = seed(ps)
+            stats = None
+            if one_round is not None:
+                raw, rlen, stats = map_stragglers(single, one_round, A,
+                                                  weight, pps)
+            elif single is not None:
+                raw, rlen = jax.vmap(single, in_axes=(None, None, 0))(
+                    A, weight, pps)
+            else:
+                raw = jnp.full(ps.shape + (R,), NONE, I32)
+                rlen = jnp.zeros(ps.shape, I32)
+            out = lanes(weight, state, paff, trow, pps, raw, rlen)
+            return out if stats is None else out + (stats,)
+
         if mesh is None:
-            self.fn = jax.jit(vmapped)
+            self.fn = jax.jit(single_pg)
             self._npad = None
         else:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -342,7 +359,7 @@ class PoolMapper:
             shard = NamedSharding(mesh,
                                   PartitionSpec(mesh.axis_names[0]))
             self.fn = jax.jit(
-                vmapped,
+                single_pg,
                 in_shardings=(repl, repl, repl, repl,
                               {k: shard for k in self._trow}, shard),
                 out_shardings=(shard,) * 6)
@@ -414,8 +431,11 @@ class PoolMapper:
         paff = p0 if paff is None else jnp.asarray(paff)
         n = self.pool.pg_num
         ps = jnp.arange(self._npad or n, dtype=jnp.uint32)
-        up, ulen, uprim, acting, alen, aprim = self.fn(
+        book_rerun_stats()
+        up, ulen, uprim, acting, alen, aprim, *stats = self.fn(
             self.arrays, weight, state, paff, self._trow, ps)
+        if stats:
+            defer_rerun_stats(stats[0])
         out = {"up": up, "up_len": ulen, "up_primary": uprim,
                "acting": acting, "acting_len": alen,
                "acting_primary": aprim}

@@ -263,6 +263,39 @@ def test_pool_mapper_mesh_equals_unsharded(mesh):
         assert np.array_equal(np.asarray(a[k]), np.asarray(b[k])), k
 
 
+def test_pool_mapper_mesh_equals_straggler_pass(mesh):
+    """At 4,096 PGs the unmeshed PoolMapper maps through the straggler
+    pass (``mapper_spec.map_stragglers``) and books its re-runs; the
+    meshed one keeps the plain vmapped loops and books none.  With
+    OSDs out, so that PGs re-run, both give the same sets."""
+    from ceph_tpu.common.perf_counters import collection
+    from ceph_tpu.crush.builder import sample_cluster_map
+    from ceph_tpu.osdmap.osdmap import (OSDMap, PgPool,
+                                        POOL_TYPE_REPLICATED)
+    from ceph_tpu.osdmap.pipeline_jax import PoolMapper
+
+    m = OSDMap(sample_cluster_map(3, 4, 4))
+    for o in range(48):
+        m.add_osd(o)
+    for o in (1, 6, 20, 33, 47):
+        m.osd_weight[o] = 0
+    m.pools[1] = PgPool(pool_type=POOL_TYPE_REPLICATED, size=3,
+                        pg_num=4096, crush_rule=0)
+
+    def reruns():
+        c = collection().dump("crush.mapper")["crush.mapper"]
+        return c["spec_rerun_pgs"], c["spec_rerun_chunks"]
+
+    base = reruns()
+    a = {k: np.asarray(v) for k, v in PoolMapper(m, 1).map_all().items()}
+    ref = reruns()
+    assert ref[0] > base[0] and ref[1] > base[1], (base, ref)
+    b = PoolMapper(m, 1, mesh=make_mesh()).map_all()
+    assert reruns() == ref
+    for k in a:
+        assert np.array_equal(a[k], np.asarray(b[k])), k
+
+
 def test_crush_tester_mesh_sweep_matches_scalar(mesh):
     """CrushTester.test_rule over the mesh: same mappings, same
     utilization tally (the all-reduced on-device counts) as the

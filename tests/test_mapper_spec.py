@@ -160,3 +160,113 @@ def test_indep_cases_covered_and_leaf_type0_rejected():
     # module swaps the Ineligible class identity in analyze's globals
     with pytest.raises(ValueError, match="type 0"):
         analyze(cmap2, 9, 4)
+
+
+# -- the straggler pass (map_stragglers) --------------------------------
+
+N_STRAGGLE = 1 << 12   # PGs per launch; chunks of N // 64 = 64 lanes
+
+
+@pytest.fixture(scope="module")
+def straggle_progs():
+    """Per rule of map_big10k (0: chooseleaf firstn host, size 3; 1:
+    chooseleaf indep host, size 11): the one-round flags, the plain
+    vmapped loops, and the straggler pass with its stats."""
+    import jax
+
+    from ceph_tpu.crush.mapper_spec import make_single_spec, map_stragglers
+
+    cmap, _ = load("map_big10k")
+    progs = {}
+    for rule, size in ((0, 3), (1, 11)):
+        single, one_round, _, arrays = make_single_spec(cmap, rule, size,
+                                                        k_tries=1)
+        progs[rule] = dict(
+            size=size,
+            arrays=jax.tree_util.tree_map(jax.numpy.asarray, arrays),
+            flags=jax.jit(lambda A, w, xs, f=one_round: jax.vmap(
+                f, in_axes=(None, None, 0))(A, w, xs)[2]),
+            plain=jax.jit(jax.vmap(single, in_axes=(None, None, 0))),
+            straggle=jax.jit(lambda A, w, xs, s=single, f=one_round:
+                             map_stragglers(s, f, A, w, xs)))
+    return cmap, progs
+
+
+def _straggle_batch(cmap, prog, case):
+    """xs and weights whose first round leaves the case's count of
+    stragglers: picked from a probe of the first round's flags, or, for
+    "most", 60% of the OSDs at weight 0 (nearly every PG re-runs)."""
+    rng = np.random.default_rng(25)
+    weight = np.full(cmap.max_devices, 0x10000, np.uint32)
+    weight[rng.choice(cmap.max_devices, 100, replace=False)] = 0
+    if case == "most":
+        weight[rng.choice(cmap.max_devices, 6000, replace=False)] = 0
+        return np.arange(N_STRAGGLE, dtype=np.uint32), weight, None
+    probe = np.arange(2 * N_STRAGGLE, dtype=np.uint32)
+    flags = np.concatenate([
+        np.asarray(prog["flags"](prog["arrays"], weight, half))
+        for half in np.split(probe, 2)])
+    k = {"none": 0, "one_chunk": 40, "many_chunks": 2 * 64 + 3}[case]
+    assert flags.sum() >= k
+    xs = np.concatenate([probe[flags][:k],
+                         probe[~flags][:N_STRAGGLE - k]])
+    return rng.permutation(xs), weight, k
+
+
+@pytest.mark.parametrize("case",
+                         ["none", "one_chunk", "many_chunks", "most"])
+@pytest.mark.parametrize("rule", [0, 1], ids=["firstn3", "indep11"])
+def test_straggler_pass_matches_plain_loops(straggle_progs, rule, case):
+    """The one-round pass plus chunked re-runs gives, bit for bit, what
+    the plain vmapped retry loops give, and on a sample what the
+    reference mapper gives, whatever the count of stragglers; its
+    stats count the stragglers and ceil(stragglers / 64) chunks."""
+    from ceph_tpu.crush.mapper_ref import crush_do_rule
+
+    cmap, progs = straggle_progs
+    prog = progs[rule]
+    xs, weight, k = _straggle_batch(cmap, prog, case)
+    A = prog["arrays"]
+    res, lens, stats = (np.asarray(v)
+                        for v in prog["straggle"](A, weight, xs))
+    want_res, want_lens = (np.asarray(v)
+                           for v in prog["plain"](A, weight, xs))
+    assert np.array_equal(res, want_res)
+    assert np.array_equal(lens, want_lens)
+
+    flags = np.asarray(prog["flags"](A, weight, xs))
+    flagged, chunks = (int(v) for v in stats)
+    assert flagged == int(flags.sum())
+    if k is not None:
+        assert flagged == k
+    else:
+        assert flagged > 0.9 * N_STRAGGLE
+    assert chunks == -(-flagged // (N_STRAGGLE // 64))
+
+    # the reference on a few stragglers and a few others
+    lanes = np.concatenate([np.nonzero(flags)[0][:6],
+                            np.nonzero(~flags)[0][:2]])
+    for i in lanes:
+        want = crush_do_rule(cmap, rule, int(xs[i]), prog["size"],
+                             weight.tolist())
+        assert list(res[i, :lens[i]]) == want, (case, int(xs[i]))
+
+
+def test_straggler_stats_booked_when_read(straggle_progs):
+    """A launch's straggler stats are kept as a device array and booked
+    in the ``crush.mapper`` counters by the next ``perf dump``."""
+    from ceph_tpu.common.perf_counters import collection
+    from ceph_tpu.crush.mapper_jax import defer_rerun_stats
+
+    cmap, progs = straggle_progs
+    prog = progs[0]
+    xs, weight, k = _straggle_batch(cmap, prog, "many_chunks")
+
+    def dump():
+        return collection().dump("crush.mapper")["crush.mapper"]
+
+    before = dump()
+    defer_rerun_stats(prog["straggle"](prog["arrays"], weight, xs)[2])
+    after = dump()
+    assert after["spec_rerun_pgs"] - before["spec_rerun_pgs"] == k
+    assert after["spec_rerun_chunks"] - before["spec_rerun_chunks"] == 3
