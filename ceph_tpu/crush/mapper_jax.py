@@ -57,6 +57,7 @@ from . import constants as C  # noqa: E402
 from . import hash as H  # noqa: E402
 from ..analysis.lockdep import make_lock  # noqa: E402
 from ..common import device_metrics  # noqa: E402
+from ..common.log import getLogger  # noqa: E402
 from ..common.perf_counters import collection  # noqa: E402
 from .ln import (LL_NP, RH_LH_NP, ln16_table, recip64,  # noqa: E402
                  straw2_draw, straw2_key)
@@ -80,6 +81,10 @@ _pc.add_histogram("map_lat")
 # them with the full retry loops
 for _k in ("spec_rerun_pgs", "spec_rerun_chunks"):
     _pc.add_u64_counter(_k)
+# rules lowered: onto the speculative program, or the general rule VM
+for _k in ("lowered_spec", "lowered_general"):
+    _pc.add_u64_counter(_k)
+_refusals_logged = set()
 _rerun_lock = make_lock("crush::reruns")
 _rerun_pending = collections.deque()   # stats i32[2] still on the device
 
@@ -105,6 +110,31 @@ def book_rerun_stats(wait: bool = False) -> None:
 
 
 _pc.before_dump(lambda: book_rerun_stats(wait=True))
+
+
+def speculative(make, ruleno: int):
+    """The speculative lowering of rule ``ruleno`` that ``make()``
+    builds (booked as ``lowered_spec``), or None where the rule needs
+    the general rule VM or ``CEPH_TPU_SPEC_PIPELINE=0`` forces it
+    (booked as ``lowered_general``; ``analyze``'s reason is logged
+    once per rule and reason)."""
+    from .mapper_spec import Ineligible
+
+    if os.environ.get("CEPH_TPU_SPEC_PIPELINE", "1") != "0":
+        try:
+            out = make()
+        except Ineligible as e:
+            if (ruleno, str(e)) not in _refusals_logged:
+                _refusals_logged.add((ruleno, str(e)))
+                # not at import: the first logger made fixes the log
+                # core's stream to the sys.stderr of that moment
+                getLogger("crush").dout(
+                    1, f"rule {ruleno} takes the general rule VM: {e}")
+        else:
+            _pc.inc("lowered_spec")
+            return out
+    _pc.inc("lowered_general")
+    return None
 
 I32 = jnp.int32
 U32 = jnp.uint32
@@ -974,17 +1004,14 @@ class BatchedMapper:
     def rule_fn(self, ruleno: int, result_max: int):
         key = (ruleno, result_max)
         if key not in self._cache:
-            fn = None
-            if os.environ.get("CEPH_TPU_SPEC_PIPELINE", "1") != "0":
-                from .mapper_spec import Ineligible, build_spec_rule_fn
+            from .mapper_spec import build_spec_rule_fn
 
-                try:
-                    fn, _, _ = build_spec_rule_fn(
-                        self.cmap, ruleno, result_max, self.choose_args,
-                        encoded=self._encoded, k_tries=1)
-                except Ineligible:
-                    fn = None
-            if fn is None:
+            spec = speculative(lambda: build_spec_rule_fn(
+                self.cmap, ruleno, result_max, self.choose_args,
+                encoded=self._encoded, k_tries=1), ruleno)
+            if spec is not None:
+                fn = spec[0]
+            else:
                 fn, _, _ = build_rule_fn(
                     self.cmap, ruleno, result_max, self.choose_args,
                     encoded=self._encoded)

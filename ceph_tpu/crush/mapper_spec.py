@@ -29,6 +29,10 @@ speculative program instead:
   lane until the slowest is done.  :func:`map_stragglers` therefore runs
   one round over the whole batch and the loops only over the lanes that
   need another round, compacted into chunks a fraction of its size.
+- ``choose(leaf) indep`` rules, and the two-step ``choose indep N type
+  T / chooseleaf indep M type U`` of Ceph's locality-aware EC rules (the
+  LRC plugin's ``crush-locality``), run the reference's breadth-first
+  rounds directly: every open slot's descent is one lane of a batch.
 
 Bit-exactness contract: identical (result, len) to ``mapper_ref.py`` /
 ``mapper_jax.py`` for every eligible (map, rule, tunables) combination —
@@ -79,7 +83,12 @@ class Ineligible(ValueError):
 
 @dataclass(frozen=True)
 class Plan:
-    """Static facts the speculative compiler needs (all trace-time)."""
+    """Static facts the speculative compiler needs (all trace-time).
+
+    A one-step rule is its ``choose`` step; a two-step rule (``choose
+    indep`` of buckets, then ``chooseleaf indep`` below each) is its
+    second step, below the ``pre_numrep`` buckets of ``pre_type`` that
+    the first picks (``pre_numrep`` 0: one step)."""
 
     root_idx: int        # bucket index of the take root
     numrep: int
@@ -90,8 +99,14 @@ class Plan:
     recurse_tries: int   # inner retry budget (1 under descend_once)
     vary_r: int
     stable: int
-    depth_outer: int     # max descent levels root -> anywhere
+    depth_outer: int     # max descent levels root (or a pre_type bucket)
+                         # -> anywhere
     depth_inner: int     # max descent levels below a type_ bucket
+    pre_numrep: int = 0  # two-step: buckets the first step picks
+    pre_type: int = 0    # two-step: their type
+    pre_tries: int = 0   # two-step: the first step's retry budget
+    pre_depth: int = 0   # two-step: descent levels root -> pre_type
+    first_rounds: int = 1  # indep rounds the one-round pass unrolls
 
 
 def _max_depth(cmap: CrushMap, idx: int, _seen=()) -> int:
@@ -111,15 +126,38 @@ def _max_depth(cmap: CrushMap, idx: int, _seen=()) -> int:
     return best
 
 
+def _depth_to(cmap: CrushMap, idx: int, type_: int, _seen=()) -> int:
+    """Levels a descent from bucket index ``idx`` looking for
+    ``type_`` can take: it stops at an item of that type, a device or
+    a missing bucket, and goes on into any other bucket."""
+    if idx in _seen:
+        raise Ineligible("bucket graph has a cycle")
+    best = 1
+    for it in cmap.buckets[idx].items:
+        b = cmap.buckets.get(-1 - it) if it < 0 else None
+        if b is not None and b.type != type_:
+            best = max(best, 1 + _depth_to(cmap, -1 - it, type_,
+                                           _seen + (idx,)))
+    return best
+
+
+# inner retry budgets unrolled in one try of the outer loop: firstn
+# unrolls all of them in the one-round pass; indep tries the first
+# there and flags a lane that needs more (Ceph's EC rules set 5)
+MAX_RECURSE_TRIES = {True: 4, False: 5}
+
+
 def analyze(cmap: CrushMap, ruleno: int, result_max: int) -> Plan:
     """Decide eligibility and extract the static plan.
 
-    Eligible iff: every bucket is straw2; the rule is one
-    ``take`` / ``choose(leaf) firstn`` / ``emit`` block (SET_* tunable
-    steps allowed); the effective local retry knobs are 0 (modern
-    tunables — mapper.c:444-449 never takes the retry_bucket or
-    perm-fallback paths then); the inner budget unrolls (<= 4); and
-    numrep fits result_max.
+    Eligible iff: every bucket is straw2; the rule is one ``take``,
+    then either one ``choose(leaf) firstn|indep`` or ``choose indep N
+    type T`` (a bucket type) followed by ``chooseleaf indep M type U``
+    (U a bucket type, N·M <= result_max), then ``emit`` (SET_* tunable
+    steps allowed); the effective local retry knobs of a firstn rule
+    are 0 (modern tunables — mapper.c:444-449 never takes the
+    retry_bucket or perm-fallback paths then); the inner budget
+    unrolls (:data:`MAX_RECURSE_TRIES`); and numrep fits result_max.
     """
     for b in cmap.buckets.values():
         if b.alg != C.CRUSH_BUCKET_STRAW2:
@@ -135,7 +173,7 @@ def analyze(cmap: CrushMap, ruleno: int, result_max: int) -> Plan:
     stable = t.chooseleaf_stable
 
     root = None
-    choose = None
+    chooses = []     # (numrep, type, leafy, firstn, tries, leaf_tries)
     emitted = False
     for step in rule.steps:
         op, arg1, arg2 = step.op, step.arg1, step.arg2
@@ -160,7 +198,7 @@ def analyze(cmap: CrushMap, ruleno: int, result_max: int) -> Plan:
             if arg1 >= 0:
                 stable = arg1
         elif op == C.CRUSH_RULE_TAKE:
-            if root is not None or choose is not None:
+            if root is not None or chooses:
                 raise Ineligible("multiple takes")
             if arg1 >= 0 or cmap.bucket_by_id(arg1) is None:
                 raise Ineligible("take target is not an existing bucket")
@@ -169,8 +207,10 @@ def analyze(cmap: CrushMap, ruleno: int, result_max: int) -> Plan:
                     C.CRUSH_RULE_CHOOSE_FIRSTN,
                     C.CRUSH_RULE_CHOOSELEAF_INDEP,
                     C.CRUSH_RULE_CHOOSE_INDEP):
-            if root is None or choose is not None:
-                raise Ineligible("choose without take / multiple chooses")
+            if root is None:
+                raise Ineligible("choose without take")
+            if len(chooses) == 2:
+                raise Ineligible("more than two chooses")
             leafy = op in (C.CRUSH_RULE_CHOOSELEAF_FIRSTN,
                            C.CRUSH_RULE_CHOOSELEAF_INDEP)
             firstn = op in (C.CRUSH_RULE_CHOOSELEAF_FIRSTN,
@@ -182,8 +222,6 @@ def analyze(cmap: CrushMap, ruleno: int, result_max: int) -> Plan:
                 raise Ineligible("numrep outside [1, result_max]")
             if numrep > 16:
                 raise Ineligible("numrep unroll bound exceeded")
-            if not leafy and arg2 != 0:
-                raise Ineligible("choose of a non-device type")
             if not firstn and leafy and arg2 == 0:
                 # the reference writes the candidate device into out2
                 # BEFORE the is_out check here (mapper.c:772-776), so
@@ -192,45 +230,73 @@ def analyze(cmap: CrushMap, ruleno: int, result_max: int) -> Plan:
                 # the complexity — fall back to the general VM
                 raise Ineligible("chooseleaf indep of type 0 "
                                  "(out2 pre-is_out leak quirk)")
-            choose = (numrep, arg2, leafy, firstn)
+            chooses.append((numrep, arg2, leafy, firstn, choose_tries,
+                            choose_leaf_tries))
         elif op == C.CRUSH_RULE_EMIT:
-            if choose is None:
+            if not chooses:
                 raise Ineligible("emit without choose")
             emitted = True
         else:
             raise Ineligible(f"unsupported step op {op}")
     if not emitted:
         raise Ineligible("rule never emits")
-    numrep, type_, leafy, firstn = choose
+    pre = None
+    if len(chooses) == 2:
+        pre = chooses[0]
+        n1, t1, leafy1, firstn1 = pre[:4]
+        n2, t2, leafy2, firstn2 = chooses[1][:4]
+        if firstn1 or leafy1 or t1 == 0 or firstn2 or not leafy2:
+            raise Ineligible("two chooses other than choose indep of a "
+                             "bucket type, then chooseleaf indep")
+        if n1 * n2 > result_max:
+            raise Ineligible("two-step numreps exceed result_max")
+    numrep, type_, leafy, firstn, tries, leaf_tries = chooses[-1]
+    if not leafy and type_ != 0 and pre is None:
+        raise Ineligible("choose of a non-device type")
     if firstn and (local_retries != 0 or local_fb != 0):
         # indep has no local-retry paths at all (mapper.c:633-821),
         # so legacy local tunables only disqualify firstn rules
         raise Ineligible("legacy local retry tunables in force")
     if leafy:
-        if choose_leaf_tries:
-            recurse_tries = choose_leaf_tries
+        if leaf_tries:
+            recurse_tries = leaf_tries
         elif firstn and t.chooseleaf_descend_once:
             recurse_tries = 1
         elif firstn:
-            recurse_tries = choose_tries
+            recurse_tries = tries
         else:
             recurse_tries = 1  # indep default (mapper_jax:692)
     else:
         recurse_tries = 1
-    if recurse_tries > 4:
+    if recurse_tries > MAX_RECURSE_TRIES[firstn]:
         raise Ineligible(f"recurse_tries {recurse_tries} unroll bound")
 
-    depth_outer = _max_depth(cmap, root)
     depth_inner = 1
     if leafy and type_ > 0:
         depths = [_max_depth(cmap, i) for i, b in cmap.buckets.items()
                   if b.type == type_]
         depth_inner = max(depths) if depths else 1
-    return Plan(root_idx=root, numrep=numrep, type_=type_, leafy=leafy,
-                firstn=firstn, tries=choose_tries,
-                recurse_tries=recurse_tries,
+    if pre is None:
+        return Plan(root_idx=root, numrep=numrep, type_=type_,
+                    leafy=leafy, firstn=firstn, tries=tries,
+                    recurse_tries=recurse_tries, vary_r=vary_r,
+                    stable=stable, depth_outer=_max_depth(cmap, root),
+                    depth_inner=depth_inner)
+    mids = [i for i, b in cmap.buckets.items() if b.type == pre[1]]
+    return Plan(root_idx=root, numrep=numrep, type_=type_, leafy=True,
+                firstn=False, tries=tries, recurse_tries=recurse_tries,
                 vary_r=vary_r, stable=stable,
-                depth_outer=depth_outer, depth_inner=depth_inner)
+                depth_outer=max([_depth_to(cmap, i, type_)
+                                 for i in mids] or [1]),
+                depth_inner=depth_inner, pre_numrep=pre[0],
+                pre_type=pre[1], pre_tries=pre[4],
+                pre_depth=_depth_to(cmap, root, pre[1]),
+                # a segment draws its M slots from one bucket (4 of a
+                # rack's 25 hosts in crush10k_lrc), so its first round
+                # collides far more often than a draw over the whole
+                # tree: after one round 42% of that pool's PGs are left
+                # to re-run, after two 5% (11% for the EC 8+3 rule)
+                first_rounds=2)
 
 
 def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
@@ -239,8 +305,9 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
     """The unjitted single-x speculative program:
     ``single(arrays, weight, x) -> (result i32[R], len i32)``, and its
     one-round variant ``one_round(arrays, weight, x) -> (result, len,
-    more bool)``: every retry loop runs its first round only, and
-    ``more`` says some loop would have run another.  Where ``more`` is
+    more bool)``: every retry loop runs its first round only (a
+    two-step rule's, its first two), an indep inner recursion its first
+    try, and ``more`` says some loop would have run further.  Where ``more`` is
     false the variant's answer is ``single``'s.
 
     Raises :class:`Ineligible` when the rule needs the general mapper.
@@ -263,12 +330,17 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
     maxdev = static.max_devices
     U64MAX = jnp.uint64(0xFFFFFFFFFFFFFFFF)
 
-    def rounds(cond, body, st, one_round, x):
+    def rounds(cond, body, st, one_round, x, first=1):
         """A retry loop: ``lax.while_loop`` in the full program; in the
-        one-round variant the body once (the first round always runs),
-        and whether the loop would go on."""
+        one-round variant its ``first`` rounds unrolled (the first
+        always runs, a later one only where the loop would), and
+        whether the loop would go on."""
         if one_round:
             st = body(st)
+            for _ in range(first - 1):
+                go = cond(st)
+                st = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(go, a, b), body(st), st)
             return st, cond(st)
         # every carry starts as a value of x's lane: vmap then batches
         # the loop in one pass of its body, not in one more per carry
@@ -371,42 +443,51 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
                     | is_out(weight, dev, x))
         return jnp.where(bad, _FAIL, st), dev
 
-    def single_indep(A, weight, x, rw, one_round):
-        """crush_choose_indep (mapper.c:633-821) as dense rounds: the
-        breadth-first structure is already a batch — every open slot's
-        descent vectorizes, with a sequential unrolled commit pass that
-        reproduces the reference's in-round collision ordering (slot j
-        sees slots < j placed this round).  Positional: failed slots
-        stay NONE."""
-        # analyze() guarantees numrep <= result_max, so the segment
-        # is exactly [0, numrep)
-        assert plan.numrep <= R
-        NR = plan.numrep
-        js = jnp.arange(plan.numrep, dtype=I32)
-        out = jnp.full(R, UNDEF, I32)    # hosts
-        out2 = jnp.full(R, UNDEF, I32)   # devices
-        root_vec = jnp.full((plan.numrep,), plan.root_idx, I32)
-        pos0 = jnp.int32(0)  # the C passes outpos (0 here) as position
+    def indep(A, weight, x, rw, start, valid, numrep, type_, leafy,
+              tries, levels, one_round):
+        """crush_choose_indep (mapper.c:633-821) as dense rounds, over
+        segments of ``numrep`` slots: segment s descends from bucket
+        index ``start[s]``, and is left out where ``valid[s]`` is
+        false.  A segment is one C call, which crush_do_rule hands the
+        out pointer ``o+osize`` and outpos 0: its ranks count from its
+        first slot, its collisions stay inside it, and its choose_args
+        position is 0.  Segments share nothing, so their rounds run side
+        by side.  The breadth-first structure is already a batch — every
+        open slot's descent vectorizes, with a sequential unrolled
+        commit pass that reproduces the reference's in-round collision
+        ordering (slot j sees slots < j placed this round).
+        Positional: failed slots stay NONE.  Returns ``(out, out2,
+        more)``, out (items) and out2 (devices) i32[segments, numrep]."""
+        NS, NR = start.shape[0], numrep
+        js = jnp.tile(jnp.arange(NR, dtype=I32), NS)
+        starts = jnp.repeat(start, NR)
+        out = jnp.broadcast_to(
+            jnp.where(valid, I32(UNDEF), I32(NONE))[:, None], (NS, NR))
+        pos0 = jnp.int32(0)
+        none1 = jnp.zeros((1,), I32)
+        # the one-round pass tries the inner descent once, and flags a
+        # slot that would try again
+        inner = 1 if one_round else plan.recurse_tries
 
         def round_cond(st):
-            ftotal, left, out, out2 = st
-            return (left > 0) & (ftotal < plan.tries)
+            ftotal, left, out, out2, cut = st
+            return jnp.any(left > 0) & (ftotal < tries)
 
         def round_body(st):
-            ftotal, left, out, out2 = st
+            ftotal, left, out, out2, cut = st
             # straw2-only: no uniform buckets, so the rank multiplier
             # is always numrep (mapper.c:653-660)
-            r = (js + plan.numrep * ftotal).astype(I32)
-            ost, host, hidx = descend(A, rw, x, root_vec, r, pos0,
-                                      plan.type_, plan.depth_outer)
+            r = (js + numrep * ftotal).astype(I32)
+            ost, host, hidx = descend(A, rw, x, starts, r, pos0, type_,
+                                      levels)
             found = ost == _OK
-            if plan.leafy and plan.type_ > 0:
-                # inner: rep=slot, parent_r=r, single round under the
-                # default recurse budget (r_in = slot + r + n*ft_in)
+            if leafy:
+                # inner: rep=slot, parent_r=r, its own rounds under the
+                # recurse budget (r_in = slot + r + numrep*ft_in)
                 dev = jnp.zeros_like(host)
-                got = jnp.zeros((plan.numrep,), bool)
-                dead = jnp.zeros((plan.numrep,), bool)
-                for t_in in range(plan.recurse_tries):
+                got = jnp.zeros(js.shape, bool)
+                dead = jnp.zeros(js.shape, bool)
+                for t_in in range(inner):
                     # the inner's choose_args position is the SLOT
                     # index (the recursion's outpos param,
                     # mapper_jax.py:546), vectorized per lane; no
@@ -414,43 +495,85 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
                     # is its own single slot (mapper_jax.py:508-516)
                     ist, d = leaf_try(
                         A, rw, weight, x, hidx,
-                        (js + r + plan.numrep * t_in).astype(I32),
-                        js, out2, jnp.int32(0))
+                        (js + r + numrep * t_in).astype(I32), js, none1,
+                        jnp.int32(0))
                     take = found & ~got & ~dead & (ist == _OK)
                     dev = jnp.where(take, d, dev)
                     got = got | take
                     dead = dead | (~got & (ist == _SKIP))
                 cand = found & got
+                if inner < plan.recurse_tries:
+                    cut = cut | jnp.any(found & ~got & ~dead &
+                                        (out.reshape(-1) == UNDEF))
+            elif type_ > 0:
+                # a bucket: no is_out (mapper.c:806 checks devices only)
+                dev, cand = host, found
             else:
                 dev = host
                 cand = found & ~is_out(weight, host, x)
 
+            ost, host, dev, cand = (v.reshape(NS, NR)
+                                    for v in (ost, host, dev, cand))
             # sequential commit: the C fills slots in order, so slot
             # j's collision check sees this round's earlier placements
-            idx = jnp.arange(R, dtype=I32)
             for j in range(NR):
-                slot_open = out[j] == UNDEF
-                collide = jnp.any((idx < NR) & (out == host[j]))
-                place = cand[j] & slot_open & ~collide
-                term = (ost[j] == _SKIP) & slot_open
-                out = jnp.where(place | term,
-                                out.at[j].set(jnp.where(place, host[j],
-                                                        NONE)), out)
-                out2 = jnp.where(place | term,
-                                 out2.at[j].set(jnp.where(place, dev[j],
-                                                          NONE)), out2)
+                slot_open = out[:, j] == UNDEF
+                collide = jnp.any(out == host[:, j:j + 1], axis=1)
+                place = cand[:, j] & slot_open & ~collide
+                term = (ost[:, j] == _SKIP) & slot_open
+                out = out.at[:, j].set(jnp.where(
+                    place, host[:, j], jnp.where(term, NONE, out[:, j])))
+                out2 = out2.at[:, j].set(jnp.where(
+                    place, dev[:, j], jnp.where(term, NONE, out2[:, j])))
                 left = left - (place | term).astype(I32)
-            return ftotal + 1, left, out, out2
+            return ftotal + 1, left, out, out2, cut
 
-        st = (jnp.int32(0), jnp.int32(NR), out, out2)
-        (_, _, out, out2), more = rounds(round_cond, round_body, st,
-                                         one_round, x)
-        result = out2 if plan.leafy else out
+        st = (jnp.int32(0), jnp.where(valid, NR, 0).astype(I32), out, out,
+              jnp.bool_(False))
+        (_, _, out, out2, cut), more = rounds(round_cond, round_body, st,
+                                              one_round, x,
+                                              plan.first_rounds)
+        return (jnp.where(out == UNDEF, NONE, out),
+                jnp.where(out2 == UNDEF, NONE, out2), more | cut)
+
+    def single_indep(A, weight, x, rw, one_round):
+        """An indep rule: one segment from the take root."""
+        out, out2, more = indep(
+            A, weight, x, rw, jnp.full((1,), plan.root_idx, I32),
+            jnp.ones((1,), bool), plan.numrep, plan.type_, plan.leafy,
+            plan.tries, plan.depth_outer, one_round)
+        # analyze() guarantees numrep <= result_max
+        result = jnp.full(R, NONE, I32).at[:plan.numrep].set(
+            (out2 if plan.leafy else out)[0])
+        return result, jnp.int32(plan.numrep), more
+
+    def two_step(A, weight, x, rw, one_round):
+        """``choose indep N type T`` from the take root, then
+        ``chooseleaf indep M`` below each bucket it picked, in order
+        (crush_do_rule, mapper.c:967-1040)."""
+        mids, _, more = indep(
+            A, weight, x, rw, jnp.full((1,), plan.root_idx, I32),
+            jnp.ones((1,), bool), plan.pre_numrep, plan.pre_type, False,
+            plan.pre_tries, plan.pre_depth, one_round)
+        # a hole (NONE; UNDEF where an unfinished first round left a
+        # slot, a lane the pass flags) is no bucket: it is skipped
+        valid = mids[0] < 0
+        _, devs, more2 = indep(
+            A, weight, x, rw, jnp.clip(-1 - mids[0], 0, B - 1), valid,
+            plan.numrep, plan.type_, True, plan.tries, plan.depth_outer,
+            one_round)
+        # ... without advancing osize: the next bucket's slots close up
+        NR = plan.numrep
+        nvalid = valid.astype(I32)
+        before = jnp.cumsum(nvalid, dtype=I32) - nvalid
         idx = jnp.arange(R, dtype=I32)
-        result = jnp.where(idx < NR,
-                           jnp.where(result == UNDEF, NONE, result),
-                           NONE)
-        return result, jnp.int32(NR), more
+        result = jnp.full(R, NONE, I32)
+        for s in range(plan.pre_numrep):
+            p = idx - before[s] * NR
+            take = valid[s] & (p >= 0) & (p < NR)
+            result = jnp.where(take, devs[s, jnp.clip(p, 0, NR - 1)],
+                               result)
+        return result, jnp.sum(nvalid, dtype=I32) * NR, more | more2
 
     def program(A, weight, x, one_round):
         # weight reciprocals: unbatched under vmap (depend only on A), so
@@ -459,6 +582,8 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
         if use_table:
             rw = recip64(A.arg_weights, xp=jnp) if static.has_choose_args \
                 else recip64(A.weights, xp=jnp)
+        if plan.pre_numrep:
+            return two_step(A, weight, x, rw, one_round)
         if not plan.firstn:
             return single_indep(A, weight, x, rw, one_round)
         more = jnp.bool_(False)

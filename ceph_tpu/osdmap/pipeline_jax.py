@@ -31,9 +31,8 @@ import jax.numpy as jnp
 from ..crush import hash as H
 from ..crush.constants import CRUSH_ITEM_NONE as NONE
 from ..crush.mapper_jax import (book_rerun_stats, defer_rerun_stats,
-                                make_single_fn)
-from ..crush.mapper_spec import (Ineligible, make_single_spec,
-                                 map_stragglers)
+                                make_single_fn, speculative)
+from ..crush.mapper_spec import make_single_spec, map_stragglers
 from .osdmap import (DEFAULT_PRIMARY_AFFINITY, FLAG_HASHPSPOOL,
                      MAX_PRIMARY_AFFINITY, OSD_EXISTS, OSD_UP, OSDMap,
                      PgPool)
@@ -160,21 +159,17 @@ class PoolMapper:
         if pool.crush_rule in m.crush.rules:
             # the speculative lowering (mapper_spec) is bit-exact and
             # ~an order of magnitude faster where eligible (straw2
-            # take/chooseleaf-firstn/emit, modern tunables) — the
-            # balancer's mutate-remap loop and osdmaptool sweeps live
-            # on this path; everything else takes the general rule VM.
-            # CEPH_TPU_SPEC_PIPELINE=0 forces the general mapper.
-            import os as _os
-
-            single = one_round = None
-            if _os.environ.get("CEPH_TPU_SPEC_PIPELINE", "1") != "0":
-                try:
-                    single, one_round, static, arrays = make_single_spec(
-                        m.crush, pool.crush_rule, R,
-                        choose_args=cargs, k_tries=1)
-                except Ineligible:
-                    single = None
-            if single is None:
+            # take / chooseleaf, or choose indep then chooseleaf indep,
+            # / emit under modern tunables) — the balancer's
+            # mutate-remap loop and osdmaptool sweeps live on this
+            # path; everything else takes the general rule VM.
+            spec = speculative(lambda: make_single_spec(
+                m.crush, pool.crush_rule, R, choose_args=cargs,
+                k_tries=1), pool.crush_rule)
+            if spec is not None:
+                single, one_round, static, arrays = spec
+            else:
+                one_round = None
                 single, static, arrays = make_single_fn(
                     m.crush, pool.crush_rule, R, choose_args=cargs)
             self.arrays = jax.tree_util.tree_map(jnp.asarray, arrays)

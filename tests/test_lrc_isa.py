@@ -184,3 +184,119 @@ def test_lrc_create_rule_and_placement():
         assert len(res) == n
         hosts = {o // 2 for o in res}
         assert len(hosts) == n  # failure-domain separation
+
+
+def _rack_cluster(racks=4, hosts=4, per_host=2):
+    from ceph_tpu.crush.wrapper import CrushWrapper
+
+    w = CrushWrapper()
+    dev = 0
+    for r in range(racks):
+        for h in range(hosts):
+            for _ in range(per_host):
+                w.insert_item(dev, 0x10000, f"osd.{dev}",
+                              {"host": f"host{r}.{h}", "rack": f"rack{r}",
+                               "root": "default"})
+                dev += 1
+    return w, dev
+
+
+LRC_RACK = {"plugin": "lrc", "k": "4", "m": "2", "l": "3",
+            "crush-locality": "rack", "crush-failure-domain": "host"}
+
+
+def test_lrc_rack_locality_rule_is_ceph_s():
+    """ErasureCodeLrc::create_rule (ErasureCodeLrc.cc:44-110) for
+    k=4 m=2 l=3 crush-locality=rack crush-failure-domain=host."""
+    from ceph_tpu.crush import constants as C
+
+    w, _ = _rack_cluster()
+    code = registry.profile_factory(dict(LRC_RACK))
+    rid = code.create_rule("lrc8", w)
+    steps = [(s.op, s.arg1, s.arg2) for s in w.crush.rules[rid].steps]
+    assert steps == [
+        (C.CRUSH_RULE_SET_CHOOSELEAF_TRIES, 5, 0),
+        (C.CRUSH_RULE_SET_CHOOSE_TRIES, 100, 0),
+        (C.CRUSH_RULE_TAKE, w.get_item_id("default"), 0),
+        (C.CRUSH_RULE_CHOOSE_INDEP, 2, w.get_type_id("rack")),
+        (C.CRUSH_RULE_CHOOSELEAF_INDEP, 4, w.get_type_id("host")),
+        (C.CRUSH_RULE_EMIT, 0, 0)]
+    assert code.get_chunk_count() == 8
+
+
+def _mapper_counters():
+    from ceph_tpu.common.perf_counters import collection
+
+    return collection().dump("crush.mapper")["crush.mapper"]
+
+
+def test_lrc_rack_locality_pool_takes_the_speculative_lowering():
+    """A PoolMapper on the LRC pool books ``lowered_spec`` and maps
+    every PG as the host's scalar OSDMap does, with an OSD down."""
+    from ceph_tpu.osdmap.osdmap import (OSD_EXISTS, OSDMap, PgPool,
+                                        POOL_TYPE_ERASURE)
+    from ceph_tpu.osdmap.pipeline_jax import PoolMapper
+
+    w, n = _rack_cluster()
+    code = registry.profile_factory(dict(LRC_RACK))
+    m = OSDMap(w.crush)
+    for osd in range(n):
+        m.add_osd(osd)
+    m.pools[3] = PgPool(pool_type=POOL_TYPE_ERASURE,
+                        size=code.get_chunk_count(), min_size=5,
+                        pg_num=32, crush_rule=code.create_rule("lrc8", w))
+    m.osd_weight[5] = 0
+    m.osd_state[5] = OSD_EXISTS         # down
+    before = _mapper_counters()
+    pm = PoolMapper(m, 3)
+    after = _mapper_counters()
+    assert after["lowered_spec"] - before["lowered_spec"] == 1
+    assert after["lowered_general"] == before["lowered_general"]
+    out = {k: np.asarray(v) for k, v in pm.map_all().items()}
+    for ps in range(32):
+        up, upp, acting, actp = m.pg_to_up_acting_osds(3, ps)
+        assert out["up"][ps, :out["up_len"][ps]].tolist() == up
+        assert out["acting"][ps, :out["acting_len"][ps]].tolist() == acting
+        assert (out["up_primary"][ps], out["acting_primary"][ps]) == \
+            (upp, actp)
+
+
+def test_refused_rule_books_general_and_logs_its_reason_once():
+    """A rule the speculative lowering refuses (stretch mode's firstn
+    two-step) books ``lowered_general`` at each PoolMapper, and its
+    reason reaches the log once."""
+    import io
+
+    from ceph_tpu.common.log import core
+    from ceph_tpu.crush import constants as C
+    from ceph_tpu.crush import mapper_jax
+    from ceph_tpu.crush.map import Rule, RuleStep
+    from ceph_tpu.osdmap.osdmap import OSDMap, PgPool
+    from ceph_tpu.osdmap.pipeline_jax import PoolMapper
+
+    w, n = _rack_cluster()
+    rid = w.crush.add_rule(Rule(steps=[
+        RuleStep(C.CRUSH_RULE_TAKE, w.get_item_id("default"), 0),
+        RuleStep(C.CRUSH_RULE_CHOOSE_FIRSTN, 0, w.get_type_id("rack")),
+        RuleStep(C.CRUSH_RULE_CHOOSELEAF_FIRSTN, 2, w.get_type_id("host")),
+        RuleStep(C.CRUSH_RULE_EMIT, 0, 0)]))
+    m = OSDMap(w.crush)
+    for osd in range(n):
+        m.add_osd(osd)
+    m.pools[1] = PgPool(pool_type=1, size=4, min_size=2, pg_num=16,
+                        crush_rule=rid)
+
+    def logged():
+        buf = io.StringIO()
+        core().dump_recent(buf)
+        return buf.getvalue().count(f"rule {rid} takes the general rule "
+                                    f"VM: two chooses other than")
+
+    mapper_jax._refusals_logged.clear()
+    before, seen = _mapper_counters(), logged()
+    PoolMapper(m, 1)
+    PoolMapper(m, 1)
+    after = _mapper_counters()
+    assert after["lowered_general"] - before["lowered_general"] == 2
+    assert after["lowered_spec"] == before["lowered_spec"]
+    assert logged() - seen == 1

@@ -167,18 +167,37 @@ def test_indep_cases_covered_and_leaf_type0_rejected():
 N_STRAGGLE = 1 << 12   # PGs per launch; chunks of N // 64 = 64 lanes
 
 
+def add_lrc_rule(cmap):
+    """The rule the LRC plugin writes for k=4 m=2 l=3 with rack
+    locality (``choose indep 2 rack / chooseleaf indep 4 host``), on
+    ``cmap`` under its root named ``default``; returns its number."""
+    from ceph_tpu.crush.wrapper import CrushWrapper
+    from ceph_tpu.ec.registry import profile_factory
+
+    w = CrushWrapper(cmap)
+    root = next(b.id for b in cmap.buckets.values() if b.type == 3)
+    w.set_item_name(root, "default")
+    return profile_factory({
+        "plugin": "lrc", "k": "4", "m": "2", "l": "3",
+        "crush-locality": "rack",
+        "crush-failure-domain": "host"}).create_rule("lrc8", w)
+
+
 @pytest.fixture(scope="module")
 def straggle_progs():
     """Per rule of map_big10k (0: chooseleaf firstn host, size 3; 1:
-    chooseleaf indep host, size 11): the one-round flags, the plain
-    vmapped loops, and the straggler pass with its stats."""
+    chooseleaf indep host, size 11) and the LRC rule (2, size 8): the
+    one-round flags, the plain vmapped loops, and the straggler pass
+    with its stats; for the LRC rule also the general rule VM."""
     import jax
 
+    from ceph_tpu.crush.mapper_jax import make_single_fn
     from ceph_tpu.crush.mapper_spec import make_single_spec, map_stragglers
 
     cmap, _ = load("map_big10k")
+    assert add_lrc_rule(cmap) == 2
     progs = {}
-    for rule, size in ((0, 3), (1, 11)):
+    for rule, size in ((0, 3), (1, 11), (2, 8)):
         single, one_round, _, arrays = make_single_spec(cmap, rule, size,
                                                         k_tries=1)
         progs[rule] = dict(
@@ -189,6 +208,11 @@ def straggle_progs():
             plain=jax.jit(jax.vmap(single, in_axes=(None, None, 0))),
             straggle=jax.jit(lambda A, w, xs, s=single, f=one_round:
                              map_stragglers(s, f, A, w, xs)))
+    # the rule the parent left to the general rule VM: also against it
+    general, _, garrays = make_single_fn(cmap, 2, 8)
+    progs[2].update(
+        general=jax.jit(jax.vmap(general, in_axes=(None, None, 0))),
+        garrays=jax.tree_util.tree_map(jax.numpy.asarray, garrays))
     return cmap, progs
 
 
@@ -215,12 +239,14 @@ def _straggle_batch(cmap, prog, case):
 
 @pytest.mark.parametrize("case",
                          ["none", "one_chunk", "many_chunks", "most"])
-@pytest.mark.parametrize("rule", [0, 1], ids=["firstn3", "indep11"])
+@pytest.mark.parametrize("rule", [0, 1, 2],
+                         ids=["firstn3", "indep11", "lrc8"])
 def test_straggler_pass_matches_plain_loops(straggle_progs, rule, case):
     """The one-round pass plus chunked re-runs gives, bit for bit, what
-    the plain vmapped retry loops give, and on a sample what the
-    reference mapper gives, whatever the count of stragglers; its
-    stats count the stragglers and ceil(stragglers / 64) chunks."""
+    the plain vmapped retry loops give (for the LRC rule, the general
+    rule VM too), and on a sample what the reference mapper gives,
+    whatever the count of stragglers; its stats count the stragglers
+    and ceil(stragglers / 64) chunks."""
     from ceph_tpu.crush.mapper_ref import crush_do_rule
 
     cmap, progs = straggle_progs
@@ -233,6 +259,11 @@ def test_straggler_pass_matches_plain_loops(straggle_progs, rule, case):
                            for v in prog["plain"](A, weight, xs))
     assert np.array_equal(res, want_res)
     assert np.array_equal(lens, want_lens)
+    if "general" in prog:
+        g_res, g_lens = (np.asarray(v) for v in
+                         prog["general"](prog["garrays"], weight, xs))
+        assert np.array_equal(res, g_res)
+        assert np.array_equal(lens, g_lens)
 
     flags = np.asarray(prog["flags"](A, weight, xs))
     flagged, chunks = (int(v) for v in stats)
@@ -270,3 +301,115 @@ def test_straggler_stats_booked_when_read(straggle_progs):
     after = dump()
     assert after["spec_rerun_pgs"] - before["spec_rerun_pgs"] == k
     assert after["spec_rerun_chunks"] - before["spec_rerun_chunks"] == 3
+
+
+# -- two-step rules: choose indep of buckets, chooseleaf indep below ----
+
+def _first_step_holes(d):
+    """Make map_big10k's dict ``d`` hold, at its root, racks 0 and 2
+    and one host of rack 1 at equal weights: a first-step descent into
+    the host finds no rack and leaves its slot empty (either slot, both
+    in some inputs)."""
+    racks = [b for b in d["buckets"] if b["type"] == 2]
+    root = next(b for b in d["buckets"] if b["type"] == 3)
+    w = racks[0]["weight"]
+    root.update(items=[racks[0]["id"], racks[2]["id"],
+                       racks[1]["items"][0]],
+                item_weights=[w] * 3, size=3, weight=3 * w)
+
+
+@pytest.mark.parametrize("case", ["all_in", "rack_out",
+                                  "first_step_holes"])
+def test_two_step_rule_matches_reference_and_general_vm(case):
+    """The LRC rule over 4,096 PGs through the straggler pass equals the
+    general rule VM on every PG and the scalar reference on a sample
+    (the straggler lanes first): every OSD in; every OSD of rack 0 out
+    (its segments run their full 100 rounds and keep holes); and a
+    first step that leaves slots empty, whose later segments close up
+    (crush_do_rule does not advance osize past a hole)."""
+    import jax
+
+    from ceph_tpu.crush.mapper_jax import make_single_fn
+    from ceph_tpu.crush.mapper_ref import crush_do_rule
+    from ceph_tpu.crush.mapper_spec import make_single_spec, map_stragglers
+
+    _, d = load("map_big10k")
+    if case == "first_step_holes":
+        _first_step_holes(d["map"])
+    cmap = CrushMap.from_dict(d["map"])
+    rule = add_lrc_rule(cmap)
+    weight = np.full(cmap.max_devices, 0x10000, np.uint32)
+    if case == "rack_out":
+        weight[:500] = 0
+    xs = np.arange(N_STRAGGLE, dtype=np.uint32) * np.uint32(2654435761)
+    single, one_round, _, arrays = make_single_spec(cmap, rule, 8,
+                                                    k_tries=1)
+    A = jax.tree_util.tree_map(jax.numpy.asarray, arrays)
+    res, lens, stats = (np.asarray(v) for v in jax.jit(
+        lambda A, w, xs: map_stragglers(single, one_round, A, w, xs))(
+            A, weight, xs))
+    general, _, garrays = make_single_fn(cmap, rule, 8)
+    want_res, want_lens = (np.asarray(v) for v in jax.jit(jax.vmap(
+        general, in_axes=(None, None, 0)))(
+            jax.tree_util.tree_map(jax.numpy.asarray, garrays), weight,
+            xs))
+    assert np.array_equal(lens, want_lens)
+    assert np.array_equal(res, want_res)
+    flags = np.asarray(jax.jit(jax.vmap(one_round, in_axes=(
+        None, None, 0)))(A, weight, xs)[2])
+    assert int(stats[0]) == int(flags.sum()) > 0
+    # a lane on the emptied rack runs the reference's 100 rounds
+    nflag = 8 if case == "rack_out" else 24
+    lanes = np.concatenate([np.nonzero(flags)[0][:nflag],
+                            np.nonzero(~flags)[0][:8]])
+    if case == "first_step_holes":
+        assert {0, 4, 8} <= set(lens.tolist())
+        lanes = np.concatenate([lanes, np.nonzero(lens < 8)[0][:16]])
+    if case == "rack_out":
+        assert (res == 0x7FFFFFFF).any()
+    for i in lanes:
+        want = crush_do_rule(cmap, rule, int(xs[i]), 8, weight.tolist())
+        assert list(res[i, :lens[i]]) == want, (case, int(xs[i]))
+
+
+def test_two_step_plan():
+    cmap, _ = load("map_big10k")
+    plan = analyze(cmap, add_lrc_rule(cmap), 8)
+    assert (plan.pre_numrep, plan.pre_type, plan.pre_tries) == (2, 2, 100)
+    assert (plan.numrep, plan.type_, plan.recurse_tries) == (4, 1, 5)
+    # root -> rack, rack -> host, host -> OSD: one level each
+    assert (plan.pre_depth, plan.depth_outer, plan.depth_inner) == \
+        (1, 1, 1)
+
+
+# every other shape with two chooses goes to the general rule VM
+OTHER_TWO_CHOOSE = {
+    "stretch_firstn": [(2, 0, 2), (6, 2, 1)],
+    "indep_then_leaf_firstn": [(3, 2, 2), (6, 4, 1)],
+    "firstn_then_leaf_indep": [(2, 2, 2), (7, 4, 1)],
+    "second_not_leaf": [(3, 2, 2), (3, 4, 1)],
+    "first_leaf": [(7, 2, 2), (7, 4, 1)],
+    "first_of_devices": [(3, 2, 0), (7, 4, 1)],
+    "leaf_of_devices": [(3, 2, 2), (7, 4, 0)],
+    "three_chooses": [(3, 2, 2), (3, 2, 1), (7, 1, 1)],
+    "over_result_max": [(3, 3, 2), (7, 4, 1)],
+}
+
+
+@pytest.mark.parametrize("form", sorted(OTHER_TWO_CHOOSE))
+def test_other_two_choose_forms_refused(form):
+    from ceph_tpu.crush import constants as CC
+    from ceph_tpu.crush.map import Rule, RuleStep
+
+    cmap, d = load("map_big10k")
+    root = d["map"]["rules"][0]["steps"][0][1]
+    cmap.rules[9] = Rule(steps=[
+        RuleStep(CC.CRUSH_RULE_SET_CHOOSELEAF_TRIES, 5, 0),
+        RuleStep(CC.CRUSH_RULE_SET_CHOOSE_TRIES, 100, 0),
+        RuleStep(CC.CRUSH_RULE_TAKE, root, 0),
+        *(RuleStep(*s) for s in OTHER_TWO_CHOOSE[form]),
+        RuleStep(CC.CRUSH_RULE_EMIT, 0, 0)])
+    # ValueError: the reload test earlier in this module swaps the
+    # Ineligible class identity in analyze's globals
+    with pytest.raises(ValueError):
+        analyze(cmap, 9, 8)
