@@ -125,9 +125,11 @@ REGISTRY: Dict[str, Dict[str, str]] = {
         # retry loops, and the chunks of N/64 lanes that ran them
         "spec_rerun_pgs": U64,
         "spec_rerun_chunks": U64,
-        # rules lowered onto the speculative program or the general VM
+        # rules lowered onto the speculative program or the general VM,
+        # and the outer levels per slot the descent bound left out
         "lowered_spec": U64,
         "lowered_general": U64,
+        "spec_levels_cut": U64,
     },
     "crush.scalar": {
         "pg_lookups": U64,

@@ -81,8 +81,10 @@ _pc.add_histogram("map_lat")
 # them with the full retry loops
 for _k in ("spec_rerun_pgs", "spec_rerun_chunks"):
     _pc.add_u64_counter(_k)
-# rules lowered: onto the speculative program, or the general rule VM
-for _k in ("lowered_spec", "lowered_general"):
+# rules lowered: onto the speculative program, or the general rule VM;
+# and the outer straw2 levels per slot that bounding a one-step rule's
+# descent by its target type left out (mapper_spec.Plan.levels_cut)
+for _k in ("lowered_spec", "lowered_general", "spec_levels_cut"):
     _pc.add_u64_counter(_k)
 _refusals_logged = set()
 _rerun_lock = make_lock("crush::reruns")
@@ -112,16 +114,18 @@ def book_rerun_stats(wait: bool = False) -> None:
 _pc.before_dump(lambda: book_rerun_stats(wait=True))
 
 
-def speculative(make, ruleno: int):
+def speculative(make, cmap: CrushMap, ruleno: int, result_max: int):
     """The speculative lowering of rule ``ruleno`` that ``make()``
-    builds (booked as ``lowered_spec``), or None where the rule needs
-    the general rule VM or ``CEPH_TPU_SPEC_PIPELINE=0`` forces it
+    builds (booked as ``lowered_spec``, with its plan's
+    ``levels_cut`` as ``spec_levels_cut``), or None where the rule
+    needs the general rule VM or ``CEPH_TPU_SPEC_PIPELINE=0`` forces it
     (booked as ``lowered_general``; ``analyze``'s reason is logged
     once per rule and reason)."""
-    from .mapper_spec import Ineligible
+    from .mapper_spec import Ineligible, analyze
 
     if os.environ.get("CEPH_TPU_SPEC_PIPELINE", "1") != "0":
         try:
+            plan = analyze(cmap, ruleno, result_max)
             out = make()
         except Ineligible as e:
             if (ruleno, str(e)) not in _refusals_logged:
@@ -132,6 +136,7 @@ def speculative(make, ruleno: int):
                     1, f"rule {ruleno} takes the general rule VM: {e}")
         else:
             _pc.inc("lowered_spec")
+            _pc.inc("spec_levels_cut", plan.levels_cut)
             return out
     _pc.inc("lowered_general")
     return None
@@ -1008,7 +1013,8 @@ class BatchedMapper:
 
             spec = speculative(lambda: build_spec_rule_fn(
                 self.cmap, ruleno, result_max, self.choose_args,
-                encoded=self._encoded, k_tries=1), ruleno)
+                encoded=self._encoded, k_tries=1), self.cmap, ruleno,
+                result_max)
             if spec is not None:
                 fn = spec[0]
             else:

@@ -99,37 +99,24 @@ class Plan:
     recurse_tries: int   # inner retry budget (1 under descend_once)
     vary_r: int
     stable: int
-    depth_outer: int     # max descent levels root (or a pre_type bucket)
-                         # -> anywhere
-    depth_inner: int     # max descent levels below a type_ bucket
+    depth_outer: int     # levels from the root (or a pre_type bucket)
+                         # to the choose's type
+    depth_inner: int     # levels from a type_ bucket to the devices
     pre_numrep: int = 0  # two-step: buckets the first step picks
     pre_type: int = 0    # two-step: their type
     pre_tries: int = 0   # two-step: the first step's retry budget
     pre_depth: int = 0   # two-step: descent levels root -> pre_type
     first_rounds: int = 1  # indep rounds the one-round pass unrolls
-
-
-def _max_depth(cmap: CrushMap, idx: int, _seen=()) -> int:
-    """Longest chain of bucket hops starting at bucket index ``idx`` (a
-    descent performs one choose per hop, so this bounds any terminating
-    descent).  Maps are forests (builder/wrapper cannot create cycles);
-    a cycle would mean the C descent doesn't terminate either."""
-    b = cmap.buckets.get(idx)
-    if b is None:
-        return 0
-    if idx in _seen:
-        raise Ineligible("bucket graph has a cycle")
-    best = 1
-    for it in b.items:
-        if it < 0 and (-1 - it) in cmap.buckets:
-            best = max(best, 1 + _max_depth(cmap, -1 - it, _seen + (idx,)))
-    return best
+    levels_cut: int = 0  # one-step: levels below type_ that a descent
+                         # from the root to the devices would add
 
 
 def _depth_to(cmap: CrushMap, idx: int, type_: int, _seen=()) -> int:
     """Levels a descent from bucket index ``idx`` looking for
     ``type_`` can take: it stops at an item of that type, a device or
-    a missing bucket, and goes on into any other bucket."""
+    a missing bucket, and goes on into any other bucket.  A descent
+    draws once per level, so this bounds every descent that
+    terminates; a cycle would not terminate in the C descent either."""
     if idx in _seen:
         raise Ineligible("bucket graph has a cycle")
     best = 1
@@ -271,17 +258,22 @@ def analyze(cmap: CrushMap, ruleno: int, result_max: int) -> Plan:
     if recurse_tries > MAX_RECURSE_TRIES[firstn]:
         raise Ineligible(f"recurse_tries {recurse_tries} unroll bound")
 
+    # every descent is bounded by the levels to its wanted type, not
+    # by the tree's depth: a level after every lane has stopped is a
+    # draw whose result is thrown away
     depth_inner = 1
     if leafy and type_ > 0:
-        depths = [_max_depth(cmap, i) for i, b in cmap.buckets.items()
-                  if b.type == type_]
-        depth_inner = max(depths) if depths else 1
+        depth_inner = max([_depth_to(cmap, i, 0)
+                           for i, b in cmap.buckets.items()
+                           if b.type == type_] or [1])
     if pre is None:
+        depth_outer = _depth_to(cmap, root, type_)
         return Plan(root_idx=root, numrep=numrep, type_=type_,
                     leafy=leafy, firstn=firstn, tries=tries,
                     recurse_tries=recurse_tries, vary_r=vary_r,
-                    stable=stable, depth_outer=_max_depth(cmap, root),
-                    depth_inner=depth_inner)
+                    stable=stable, depth_outer=depth_outer,
+                    depth_inner=depth_inner,
+                    levels_cut=max(0, _depth_to(cmap, root, 0) - depth_outer))
     mids = [i for i, b in cmap.buckets.items() if b.type == pre[1]]
     return Plan(root_idx=root, numrep=numrep, type_=type_, leafy=True,
                 firstn=False, tries=tries, recurse_tries=recurse_tries,

@@ -165,7 +165,7 @@ class PoolMapper:
             # path; everything else takes the general rule VM.
             spec = speculative(lambda: make_single_spec(
                 m.crush, pool.crush_rule, R, choose_args=cargs,
-                k_tries=1), pool.crush_rule)
+                k_tries=1), m.crush, pool.crush_rule, R)
             if spec is not None:
                 single, one_round, static, arrays = spec
             else:

@@ -382,6 +382,114 @@ def test_two_step_plan():
         (1, 1, 1)
 
 
+# -- descents bounded by the levels to their wanted type ----------------
+
+@pytest.mark.parametrize("name,rule,depths", [
+    ("map_big10k", 0, (2, 1)), ("map_big10k", 1, (2, 1)),
+    ("map_tree3", 0, (1, 2))])
+def test_one_step_plan_descends_to_the_target_type(name, rule, depths):
+    """A one-step rule's outer descent stops at the choose's type: on
+    map_big10k root -> rack -> host, not a third level below the hosts;
+    on map_tree3 one level to the racks, then two to the devices."""
+    cmap, d = load(name)
+    numrep = next(c["numrep"] for c in d["cases"] if c["ruleno"] == rule)
+    plan = analyze(cmap, rule, numrep)
+    assert (plan.depth_outer, plan.depth_inner) == depths
+
+
+HOST, ROW, RACK, ROOT = 1, 2, 3, 4
+
+
+def uneven_map():
+    """Hosts of three OSDs at three depths below one root: two directly
+    under it, two under a rack, two under a row under a rack (item
+    weights uneven); rule 0 is ``chooseleaf firstn 3 type host``, rule
+    1 ``chooseleaf indep 4 type host``."""
+    from ceph_tpu.crush.builder import add_simple_rule, make_straw2_bucket
+
+    cmap = CrushMap()
+    osds = iter(range(18))
+
+    def bucket(type_, children):
+        b = make_straw2_bucket([c.id for c in children],
+                               [c.weight for c in children], type_)
+        cmap.add_bucket(b)
+        return b
+
+    hosts = []
+    for i in range(6):
+        ids = [next(osds) for _ in range(3)]
+        b = make_straw2_bucket(ids, [0x10000 * (1 + (i + j) % 3)
+                                     for j in range(3)], HOST)
+        cmap.add_bucket(b)
+        hosts.append(b)
+    row = bucket(ROW, hosts[4:])
+    root = bucket(ROOT, hosts[:2] + [bucket(RACK, hosts[2:4]),
+                                     bucket(RACK, [row])])
+    add_simple_rule(cmap, root.id, HOST, firstn=True, ruleno=0)
+    add_simple_rule(cmap, root.id, HOST, firstn=False, ruleno=1)
+    return cmap
+
+
+@pytest.mark.parametrize("k_tries", [1, 8])
+@pytest.mark.parametrize("rule,size", [(0, 3), (1, 4)],
+                         ids=["firstn3", "indep4"])
+def test_hosts_at_uneven_depths_match_reference(rule, size, k_tries):
+    """Where the wanted type sits at several depths the bound is the
+    deepest (root -> rack -> row -> host), and a lane that finds its
+    host early waits out the levels below it: the speculative program
+    through the straggler pass (4,096 lanes), and the plain loops,
+    give what the reference gives, with two OSDs out."""
+    import jax
+
+    from ceph_tpu.crush.mapper_ref import crush_do_rule
+    from ceph_tpu.crush.mapper_spec import make_single_spec
+
+    cmap = uneven_map()
+    plan = analyze(cmap, rule, size)
+    assert (plan.depth_outer, plan.depth_inner, plan.levels_cut) == \
+        (3, 1, 1)
+    weight = np.full(cmap.max_devices, 0x10000, np.uint32)
+    weight[[4, 13]] = 0
+    xs = np.arange(N_STRAGGLE, dtype=np.uint32) * np.uint32(2654435761)
+    m = SpeculativeMapper(cmap, k_tries=k_tries)
+    res, lens = (np.asarray(v) for v in m.map_batch(rule, xs, size,
+                                                    weight))
+    single, _, _, _ = make_single_spec(cmap, rule, size, k_tries=k_tries)
+    plain = jax.jit(jax.vmap(single, in_axes=(None, None, 0)))
+    p_res, p_lens = (np.asarray(v) for v in plain(m.arrays, weight,
+                                                  xs[:300]))
+    for i in range(300):
+        want = crush_do_rule(cmap, rule, int(xs[i]), size, weight.tolist())
+        assert list(res[i, :lens[i]]) == want, int(xs[i])
+        assert list(p_res[i, :p_lens[i]]) == want, int(xs[i])
+
+
+@pytest.mark.parametrize("rule,cut", [(0, 1), ("lrc8", 0)],
+                         ids=["firstn3", "lrc8"])
+def test_spec_levels_cut_booked_per_lowering(rule, cut):
+    """Each speculative lowering books the outer levels per slot that
+    the bound left out: one below crush10k's hosts for its replicated
+    rule, none for the LRC rule, whose descents were already bounded
+    by their types."""
+    from ceph_tpu.common.perf_counters import collection
+    from ceph_tpu.crush.mapper_jax import BatchedMapper
+
+    cmap, _ = load("map_big10k")
+    size = 3
+    if rule == "lrc8":
+        rule, size = add_lrc_rule(cmap), 8
+
+    def dump():
+        return collection().dump("crush.mapper")["crush.mapper"]
+
+    before = dump()
+    BatchedMapper(cmap).rule_fn(rule, size)
+    after = dump()
+    assert after["lowered_spec"] - before["lowered_spec"] == 1
+    assert after["spec_levels_cut"] - before["spec_levels_cut"] == cut
+
+
 # every other shape with two chooses goes to the general rule VM
 OTHER_TWO_CHOOSE = {
     "stretch_firstn": [(2, 0, 2), (6, 2, 1)],
