@@ -38,16 +38,14 @@ Bit-exactness contract: identical (result, len) to ``mapper_ref.py`` /
 ``mapper_jax.py`` for every eligible (map, rule, tunables) combination —
 asserted for all golden maps in ``tests/test_mapper_spec.py``.  Eligible
 rules are detected by :func:`analyze`; ineligible ones raise
-:class:`Ineligible` and callers fall back to the general mapper.
+:class:`Ineligible`, and ``lowering.lower_rule`` lowers them onto the
+general rule VM instead.
 """
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import Optional
-
-import numpy as np
 
 import jax
 
@@ -60,8 +58,7 @@ from jax import lax  # noqa: E402
 
 from . import constants as C  # noqa: E402
 from . import hash as H  # noqa: E402
-from .ln import (LL_NP, RH_LH_NP, ln16_table, recip64,  # noqa: E402
-                 straw2_draw, straw2_key)
+from .ln import ln16_table, recip64, straw2_key  # noqa: E402
 from .map import ChooseArgMap, CrushMap  # noqa: E402
 from .map_arrays import encode_map  # noqa: E402
 
@@ -308,13 +305,9 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
     plan = analyze(cmap, ruleno, result_max)
     static, arrays_np = encoded if encoded is not None \
         else encode_map(cmap, choose_args)
-    mode = os.environ.get("CEPH_TPU_STRAW2", "")
-    if mode not in ("table", "compute"):
-        mode = "table"  # best in this flat-shaped program on every backend
-    use_table = mode == "table"
-    ln16 = jnp.asarray(ln16_table()) if use_table else None
-    tabs = None if use_table else (jnp.asarray(RH_LH_NP),
-                                   jnp.asarray(LL_NP))
+    # the straw2 key from the LN16 table and weight reciprocals: no
+    # divide, and best in this flat-shaped program on every backend
+    ln16 = jnp.asarray(ln16_table())
     S = static.max_size
     B = static.max_buckets
     R = result_max
@@ -348,11 +341,11 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
         if static.has_choose_args:
             p = jnp.minimum(pos, static.max_positions - 1)
             wts = A.arg_weights[cur, p]
-            rec = rw[cur, p] if use_table else None
+            rec = rw[cur, p]
             ids = A.arg_ids[cur]
         else:
             wts = A.weights[cur]
-            rec = rw[cur] if use_table else None
+            rec = rw[cur]
             ids = A.items[cur]
         h = H.crush_hash32_3(jnp.uint32(x), ids.astype(U32),
                              r[:, None].astype(U32))
@@ -360,14 +353,9 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
                       h, jnp.uint32(0))
         lane = jnp.arange(S, dtype=I32)
         in_bucket = lane[None, :] < A.size[cur][:, None]
-        if use_table:
-            keys = straw2_key(h, wts, rec, xp=jnp, ln_tab=ln16)
-            keys = jnp.where(in_bucket, keys, U64MAX)
-            return A.items[cur, jnp.argmin(keys, axis=1)]
-        draws = straw2_draw(h & jnp.uint32(0xFFFF), wts, xp=jnp,
-                            tables=tabs)
-        draws = jnp.where(in_bucket, draws, jnp.int64(C.S64_MIN))
-        return A.items[cur, jnp.argmax(draws, axis=1)]
+        keys = straw2_key(h, wts, rec, xp=jnp, ln_tab=ln16)
+        keys = jnp.where(in_bucket, keys, U64MAX)
+        return A.items[cur, jnp.argmin(keys, axis=1)]
 
     def classify(A, item):
         is_neg = item < 0
@@ -481,10 +469,10 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
                 dead = jnp.zeros(js.shape, bool)
                 for t_in in range(inner):
                     # the inner's choose_args position is the SLOT
-                    # index (the recursion's outpos param,
-                    # mapper_jax.py:546), vectorized per lane; no
-                    # device dedup: the inner indep's collide segment
-                    # is its own single slot (mapper_jax.py:508-516)
+                    # index (the recursion's outpos param in
+                    # mapper_jax.make_choose_indep), vectorized per
+                    # lane; no device dedup: the inner indep's collide
+                    # segment is its own single slot (there too)
                     ist, d = leaf_try(
                         A, rw, weight, x, hidx,
                         (js + r + numrep * t_in).astype(I32), js, none1,
@@ -570,10 +558,8 @@ def make_single_spec(cmap: CrushMap, ruleno: int, result_max: int,
     def program(A, weight, x, one_round):
         # weight reciprocals: unbatched under vmap (depend only on A), so
         # they are computed once per launch, not per lane
-        rw = None
-        if use_table:
-            rw = recip64(A.arg_weights, xp=jnp) if static.has_choose_args \
-                else recip64(A.weights, xp=jnp)
+        rw = recip64(A.arg_weights, xp=jnp) if static.has_choose_args \
+            else recip64(A.weights, xp=jnp)
         if plan.pre_numrep:
             return two_step(A, weight, x, rw, one_round)
         if not plan.firstn:
@@ -724,40 +710,3 @@ def build_spec_rule_fn(cmap: CrushMap, ruleno: int, result_max: int,
 
     return jax.jit(map_batch), static, arrays_np
 
-
-class SpeculativeMapper:
-    """Drop-in alternative to ``BatchedMapper`` for eligible rules.
-
-    >>> m = SpeculativeMapper(cmap)          # raises Ineligible lazily
-    >>> res, lens = m.map_batch(ruleno, xs, result_max, weight)
-    """
-
-    def __init__(self, cmap: CrushMap,
-                 choose_args: Optional[ChooseArgMap] = None,
-                 k_tries: int = 8):
-        self.cmap = cmap
-        self.choose_args = choose_args
-        self.k_tries = k_tries
-        self._cache = {}
-        self._encoded = encode_map(cmap, choose_args)
-        self._arrays = jax.tree_util.tree_map(jnp.asarray,
-                                              self._encoded[1])
-
-    def rule_fn(self, ruleno: int, result_max: int):
-        key = (ruleno, result_max)
-        if key not in self._cache:
-            fn, _, _ = build_spec_rule_fn(
-                self.cmap, ruleno, result_max, self.choose_args,
-                encoded=self._encoded, k_tries=self.k_tries)
-            self._cache[key] = fn
-        return self._cache[key]
-
-    @property
-    def arrays(self):
-        return self._arrays
-
-    def map_batch(self, ruleno: int, xs, result_max: int, weight):
-        fn = self.rule_fn(ruleno, result_max)
-        xs = jnp.asarray(np.asarray(xs, np.uint32))
-        weight = jnp.asarray(np.asarray(weight, np.uint32))
-        return fn(self._arrays, weight, xs)

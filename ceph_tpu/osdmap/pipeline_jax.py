@@ -30,9 +30,8 @@ import jax.numpy as jnp
 
 from ..crush import hash as H
 from ..crush.constants import CRUSH_ITEM_NONE as NONE
-from ..crush.mapper_jax import (book_rerun_stats, defer_rerun_stats,
-                                make_single_fn, speculative)
-from ..crush.mapper_spec import make_single_spec, map_stragglers
+from ..crush.lowering import book_rerun_stats, defer_rerun_stats, lower_rule
+from ..crush.mapper_spec import map_stragglers
 from .osdmap import (DEFAULT_PRIMARY_AFFINITY, FLAG_HASHPSPOOL,
                      MAX_PRIMARY_AFFINITY, OSD_EXISTS, OSD_UP, OSDMap,
                      PgPool)
@@ -157,22 +156,11 @@ class PoolMapper:
 
         cargs = m.crush.choose_args.get(pool_id)
         if pool.crush_rule in m.crush.rules:
-            # the speculative lowering (mapper_spec) is bit-exact and
-            # ~an order of magnitude faster where eligible (straw2
-            # take / chooseleaf, or choose indep then chooseleaf indep,
-            # / emit under modern tunables) — the balancer's
-            # mutate-remap loop and osdmaptool sweeps live on this
-            # path; everything else takes the general rule VM.
-            spec = speculative(lambda: make_single_spec(
-                m.crush, pool.crush_rule, R, choose_args=cargs,
-                k_tries=1), m.crush, pool.crush_rule, R)
-            if spec is not None:
-                single, one_round, static, arrays = spec
-            else:
-                one_round = None
-                single, static, arrays = make_single_fn(
-                    m.crush, pool.crush_rule, R, choose_args=cargs)
-            self.arrays = jax.tree_util.tree_map(jnp.asarray, arrays)
+            lo = lower_rule(m.crush, pool.crush_rule, R,
+                            choose_args=cargs)
+            single, one_round = lo.single, lo.one_round
+            self.arrays = jax.tree_util.tree_map(jnp.asarray,
+                                                 lo.arrays_np)
         else:
             single = one_round = None
             self.arrays = None

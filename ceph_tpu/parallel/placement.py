@@ -41,7 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..crush.map import ChooseArgMap, CrushMap
 from ..crush.map_arrays import encode_map
-from ..crush.mapper_jax import book_map_batch, build_rule_fn
+from ..crush.lowering import book_map_batch, lower_rule
 from .meshctx import pad_batch  # noqa: F401  (re-export; see meshctx)
 from . import meshctx
 
@@ -105,6 +105,10 @@ def sharded_rule_fn(cmap: CrushMap, ruleno: int, result_max: int,
     """Compile the batched mapper sharded over ``mesh`` — the engine
     behind ``PlacementPlane``.
 
+    The rule takes the lowering ``lowering.lower_rule`` picks, vmapped
+    with its plain retry loops: the straggler pass gathers across the
+    PG axis, which the mesh shards.
+
     Returns ``fn(arrays, weight, xs)`` (or ``fn(arrays, weight, xs,
     valid)`` when ``masked``) where ``xs`` is sharded on the PG axis,
     the map arrays and weight vector are replicated, results stay
@@ -113,8 +117,9 @@ def sharded_rule_fn(cmap: CrushMap, ruleno: int, result_max: int,
     ``xs``) that zeroes pad lanes out of the tally — the pad-and-mask
     half of the pow2 padding story.
     """
-    fn, static, arrays = build_rule_fn(cmap, ruleno, result_max,
-                                       choose_args, encoded=encoded)
+    lo = lower_rule(cmap, ruleno, result_max, choose_args,
+                    encoded=encoded)
+    fn = jax.vmap(lo.single, in_axes=(None, None, 0))
     repl = NamedSharding(mesh, P())
     shard = NamedSharding(mesh, P(axis_name))
 
@@ -124,7 +129,7 @@ def sharded_rule_fn(cmap: CrushMap, ruleno: int, result_max: int,
             if gather_stats:
                 counts = utilization(
                     res, jnp.where(valid, lens, 0),
-                    static.max_devices)
+                    lo.static.max_devices)
                 return res, lens, counts
             return res, lens
 
@@ -133,7 +138,7 @@ def sharded_rule_fn(cmap: CrushMap, ruleno: int, result_max: int,
         def step(A, weight, xs):
             res, lens = fn(A, weight, xs)
             if gather_stats:
-                counts = utilization(res, lens, static.max_devices)
+                counts = utilization(res, lens, lo.static.max_devices)
                 return res, lens, counts
             return res, lens
 
@@ -141,7 +146,7 @@ def sharded_rule_fn(cmap: CrushMap, ruleno: int, result_max: int,
 
     out_sh = (shard, shard, repl) if gather_stats else (shard, shard)
     sharded = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh)
-    return sharded, static, arrays
+    return sharded, lo.static, lo.arrays_np
 
 
 class PlacementPlane:

@@ -97,7 +97,7 @@ class CrushTester:
             res, ln = NativeMapper(cmap).map_batch(
                 ruleno, xs, num_rep, weights)
         else:
-            from ..crush.mapper_jax import BatchedMapper
+            from ..crush.lowering import BatchedMapper
 
             res, ln = BatchedMapper(cmap).map_batch(
                 ruleno, xs, num_rep, weights)

@@ -85,25 +85,26 @@ def test_fused_ec_kernel_compiles(one_chip, k, m, rows):
             + ma.temp_size_in_bytes) <= V5E_HBM
 
 
-@pytest.mark.parametrize("spec", ["1", "0"], ids=["spec", "general_vm"])
+@pytest.mark.parametrize("spec", [True, False], ids=["spec", "general_vm"])
 def test_crushtool_launch_fits_chip(one_chip, monkeypatch, spec):
     """``crushtool --test`` over chip_smoke's 2^20 inputs on the 10k-OSD
     map, at the launch size ``BatchedMapper`` picks on an idle chip
     (half its HBM): the speculative lowering maps the range in one
-    launch; the general rule VM (``CEPH_TPU_SPEC_PIPELINE=0``, the
-    path of rules the speculative lowering refuses), at about 0.5 MB
-    per lane, in several.  Either launch compiles and fits."""
+    launch; the general rule VM (``build_rule_fn``, the path of rules
+    the speculative lowering refuses), at about 0.5 MB per lane, in
+    several.  Either launch compiles and fits."""
     import json
 
+    from ceph_tpu.crush.lowering import BatchedMapper, fit_lanes
     from ceph_tpu.crush.map import CrushMap
-    from ceph_tpu.crush.mapper_jax import BatchedMapper, fit_lanes
+    from ceph_tpu.crush.mapper_jax import build_rule_fn
 
     monkeypatch.setenv("CEPH_TPU_STRAW2", "table")
-    monkeypatch.setenv("CEPH_TPU_SPEC_PIPELINE", spec)
     cmap = CrushMap.from_dict(
         json.load(open(GOLDEN_DIR / "map_big10k.json"))["map"])
     bm = BatchedMapper(cmap)
-    fn = bm.rule_fn(0, 3)
+    fn = bm.rule_fn(0, 3) if spec else \
+        build_rule_fn(cmap, 0, 3, encoded=bm._encoded)[0]
     arrays = jax.tree_util.tree_map(lambda a: _shape(a, one_chip),
                                     bm._encoded[1])
     weight = jax.ShapeDtypeStruct((cmap.max_devices,), jnp.uint32,
@@ -116,7 +117,7 @@ def test_crushtool_launch_fits_chip(one_chip, monkeypatch, spec):
 
     n = 1 << 20
     lanes = fit_lanes(n, compile_at, V5E_HBM // 2)
-    assert (lanes == n) == (spec == "1"), lanes
+    assert (lanes == n) == spec, lanes
     ma = compile_at(lanes).memory_analysis()
     need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes)

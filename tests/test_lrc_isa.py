@@ -269,7 +269,7 @@ def test_refused_rule_books_general_and_logs_its_reason_once():
 
     from ceph_tpu.common.log import core
     from ceph_tpu.crush import constants as C
-    from ceph_tpu.crush import mapper_jax
+    from ceph_tpu.crush import lowering
     from ceph_tpu.crush.map import Rule, RuleStep
     from ceph_tpu.osdmap.osdmap import OSDMap, PgPool
     from ceph_tpu.osdmap.pipeline_jax import PoolMapper
@@ -292,7 +292,7 @@ def test_refused_rule_books_general_and_logs_its_reason_once():
         return buf.getvalue().count(f"rule {rid} takes the general rule "
                                     f"VM: two chooses other than")
 
-    mapper_jax._refusals_logged.clear()
+    lowering._refusals_logged.clear()
     before, seen = _mapper_counters(), logged()
     PoolMapper(m, 1)
     PoolMapper(m, 1)

@@ -19,7 +19,7 @@ from ceph_tpu.crush.builder import (add_simple_rule, make_list_bucket,
                                     make_uniform_bucket,
                                     sample_cluster_map)
 from ceph_tpu.crush.map import CrushMap, Rule, RuleStep
-from ceph_tpu.crush.mapper_jax import BatchedMapper
+from ceph_tpu.crush.lowering import BatchedMapper
 from ceph_tpu.crush.mapper_ref import crush_do_rule
 
 

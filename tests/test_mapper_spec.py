@@ -4,7 +4,6 @@ Same corpus as test_mapper_jax.py restricted to eligible cases (straw2-only
 maps, take/chooseleaf-firstn/emit rules, modern tunables) — the speculative
 program must be bit-exact there, and `analyze` must correctly refuse
 everything else (legacy tunables, other bucket algs, multi-step rules).
-Both straw2 lowerings (LN16-table key and computed-ln draw) are covered.
 """
 
 import json
@@ -15,8 +14,8 @@ import pytest
 from conftest import GOLDEN_DIR
 
 from ceph_tpu.crush.map import CrushMap
-from ceph_tpu.crush.mapper_spec import (Ineligible, SpeculativeMapper,
-                                        analyze)
+from ceph_tpu.crush.mapper_spec import (Ineligible, analyze,
+                                        build_spec_rule_fn)
 
 MAP_FILES = [
     "map_flat12", "map_tree3", "map_tree3_chooseargs", "map_tree3_legacy",
@@ -44,7 +43,7 @@ def load(name):
 def test_golden_eligible_cases(name, k_tries):
     cmap, d = load(name)
     cargs = cmap.choose_args.get("golden")
-    mapper = None
+    fns = {}
     covered = 0
     for case in d["cases"]:
         ruleno, numrep = case["ruleno"], case["numrep"]
@@ -52,14 +51,15 @@ def test_golden_eligible_cases(name, k_tries):
             analyze(cmap, ruleno, numrep)
         except Ineligible:
             continue
-        if mapper is None:
-            mapper = SpeculativeMapper(cmap, choose_args=cargs,
-                                       k_tries=k_tries)
+        if (ruleno, numrep) not in fns:
+            fns[ruleno, numrep] = build_spec_rule_fn(
+                cmap, ruleno, numrep, cargs, k_tries=k_tries)
+        fn, _, arrays = fns[ruleno, numrep]
         weight = np.asarray(case["weight"], np.uint32)
         x0, x1 = case["x0"], case["x1"]
         n = min(x1 - x0, 48 if name == "map_big10k" else x1 - x0)
         xs = np.arange(x0, x0 + n, dtype=np.uint32)
-        res, lens = mapper.map_batch(ruleno, xs, numrep, weight)
+        res, lens = fn(arrays, weight, xs)
         res = np.asarray(res)
         lens = np.asarray(lens)
         for i in range(n):
@@ -86,30 +86,6 @@ def test_eligibility_judgments():
             analyze(cmap, ruleno, numrep)
 
 
-def test_compute_mode_matches_table_mode(monkeypatch):
-    """Both straw2 lowerings agree with the golden vectors (the table
-    mode is exercised by the parametrized test above; this pins the
-    computed-ln mode)."""
-    import importlib
-
-    import ceph_tpu.crush.mapper_spec as MS
-    monkeypatch.setenv("CEPH_TPU_STRAW2", "compute")
-    importlib.reload(MS)
-    try:
-        cmap, d = load("map_tree3")
-        case = d["cases"][0]
-        m = MS.SpeculativeMapper(cmap)
-        weight = np.asarray(case["weight"], np.uint32)
-        xs = np.arange(case["x0"], case["x1"], dtype=np.uint32)
-        res, lens = m.map_batch(case["ruleno"], xs, case["numrep"], weight)
-        res, lens = np.asarray(res), np.asarray(lens)
-        for i, want in enumerate(case["results"]):
-            assert list(res[i, :lens[i]]) == want
-    finally:
-        monkeypatch.delenv("CEPH_TPU_STRAW2")
-        importlib.reload(MS)
-
-
 def test_indep_cases_covered_and_leaf_type0_rejected():
     """The indep lowering: eligible golden indep cases are bit-exact
     (covered by the parametrized sweep), chooseleaf-indep-of-type-0 is
@@ -133,12 +109,9 @@ def test_indep_cases_covered_and_leaf_type0_rejected():
     weights = list(case["weight"])
     for _ in range(40):
         weights[rng.randrange(len(weights))] = 0
-    m = SpeculativeMapper(cmap, k_tries=1)
-    import numpy as np
-
+    fn, _, arrays = build_spec_rule_fn(cmap, 1, case["numrep"], k_tries=1)
     xs = np.arange(500, 564, dtype=np.uint32)
-    res, lens = m.map_batch(1, xs, case["numrep"],
-                            np.asarray(weights, np.uint32))
+    res, lens = fn(arrays, np.asarray(weights, np.uint32), xs)
     res, lens = np.asarray(res), np.asarray(lens)
     for i, x in enumerate(xs):
         want = crush_do_rule(cmap, 1, int(x), case["numrep"],
@@ -156,9 +129,7 @@ def test_indep_cases_covered_and_leaf_type0_rejected():
         RuleStep(CC.CRUSH_RULE_TAKE, root_id, 0),
         RuleStep(CC.CRUSH_RULE_CHOOSELEAF_INDEP, 4, 0),
         RuleStep(CC.CRUSH_RULE_EMIT, 0, 0)])
-    # match on the ValueError base: the reload test earlier in this
-    # module swaps the Ineligible class identity in analyze's globals
-    with pytest.raises(ValueError, match="type 0"):
+    with pytest.raises(Ineligible, match="type 0"):
         analyze(cmap2, 9, 4)
 
 
@@ -287,7 +258,7 @@ def test_straggler_stats_booked_when_read(straggle_progs):
     """A launch's straggler stats are kept as a device array and booked
     in the ``crush.mapper`` counters by the next ``perf dump``."""
     from ceph_tpu.common.perf_counters import collection
-    from ceph_tpu.crush.mapper_jax import defer_rerun_stats
+    from ceph_tpu.crush.lowering import defer_rerun_stats
 
     cmap, progs = straggle_progs
     prog = progs[0]
@@ -452,12 +423,12 @@ def test_hosts_at_uneven_depths_match_reference(rule, size, k_tries):
     weight = np.full(cmap.max_devices, 0x10000, np.uint32)
     weight[[4, 13]] = 0
     xs = np.arange(N_STRAGGLE, dtype=np.uint32) * np.uint32(2654435761)
-    m = SpeculativeMapper(cmap, k_tries=k_tries)
-    res, lens = (np.asarray(v) for v in m.map_batch(rule, xs, size,
-                                                    weight))
+    fn, _, arrays = build_spec_rule_fn(cmap, rule, size,
+                                       k_tries=k_tries)
+    res, lens = (np.asarray(v) for v in fn(arrays, weight, xs))
     single, _, _, _ = make_single_spec(cmap, rule, size, k_tries=k_tries)
     plain = jax.jit(jax.vmap(single, in_axes=(None, None, 0)))
-    p_res, p_lens = (np.asarray(v) for v in plain(m.arrays, weight,
+    p_res, p_lens = (np.asarray(v) for v in plain(arrays, weight,
                                                   xs[:300]))
     for i in range(300):
         want = crush_do_rule(cmap, rule, int(xs[i]), size, weight.tolist())
@@ -473,7 +444,7 @@ def test_spec_levels_cut_booked_per_lowering(rule, cut):
     rule, none for the LRC rule, whose descents were already bounded
     by their types."""
     from ceph_tpu.common.perf_counters import collection
-    from ceph_tpu.crush.mapper_jax import BatchedMapper
+    from ceph_tpu.crush.lowering import BatchedMapper
 
     cmap, _ = load("map_big10k")
     size = 3
@@ -517,7 +488,5 @@ def test_other_two_choose_forms_refused(form):
         RuleStep(CC.CRUSH_RULE_TAKE, root, 0),
         *(RuleStep(*s) for s in OTHER_TWO_CHOOSE[form]),
         RuleStep(CC.CRUSH_RULE_EMIT, 0, 0)])
-    # ValueError: the reload test earlier in this module swaps the
-    # Ineligible class identity in analyze's globals
-    with pytest.raises(ValueError):
+    with pytest.raises(Ineligible):
         analyze(cmap, 9, 8)

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ceph_tpu.analysis import jaxcheck
 from ceph_tpu.crush.builder import sample_cluster_map
 from ceph_tpu.crush.map import CrushMap
-from ceph_tpu.crush.mapper_jax import BatchedMapper, build_rule_fn
+from ceph_tpu.crush.lowering import BatchedMapper
 from ceph_tpu.crush import mapper_ref
 from ceph_tpu.ec.rs_jax import RSCode
 from ceph_tpu.parallel.placement import (PlacementPlane, make_mesh,
@@ -128,7 +128,7 @@ def test_sharded_ec_encode_equals_single_device(mesh):
                                            (1, 6)])
 def test_placement_plane_bit_exact_grid(mesh, cmap, ruleno, numrep):
     """Sharded results/lens/utilization identical to the unsharded
-    ``build_rule_fn`` output across the rule 0/1 (firstn/indep) x R
+    ``BatchedMapper`` output across the rule 0/1 (firstn/indep) x R
     grid — including a batch NOT divisible by the mesh (pad lanes
     masked out of the tally)."""
     weight = np.full(cmap.max_devices, 0x10000, np.uint32)

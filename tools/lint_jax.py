@@ -59,12 +59,13 @@ SUPPRESS_MARK = "jax-ok:"
 _JAX_ROOTS = {"jnp", "jax", "lax"}
 
 # modules where a host-device sync point is a throughput bug, not a
-# style point: the EC engines, both CRUSH lowerings, the fused OSDMap
-# pipeline, and the mesh data plane
+# style point: the EC engines, both CRUSH lowerings and the module that
+# picks between them, the fused OSDMap pipeline, and the mesh data plane
 HOT_MODULES = (
     "ec/engine.py",
     "ec/rs_jax.py",
     "ec/pallas_kernels.py",
+    "crush/lowering.py",
     "crush/mapper_jax.py",
     "crush/mapper_spec.py",
     "crush/ln.py",
@@ -337,11 +338,8 @@ def lint_paths(paths: Iterable[pathlib.Path]) -> List[Violation]:
 # agree with the test about what is clean).
 ALLOWLIST = (
     # batch ingest: normalize caller arrays once before device upload
-    ("crush/mapper_jax.py", "JAX002", "np.asarray(xs, np.uint32)"),
-    ("crush/mapper_jax.py", "JAX002", "np.asarray(weight, np.uint32)"),
-    ("crush/mapper_spec.py", "JAX002", "np.asarray(xs, np.uint32)"),
-    ("crush/mapper_spec.py", "JAX002",
-     "np.asarray(weight, np.uint32)"),
+    ("crush/lowering.py", "JAX002", "np.asarray(xs, np.uint32)"),
+    ("crush/lowering.py", "JAX002", "np.asarray(weight, np.uint32)"),
     # the explicit *_np host-egress API of the RS facade
     ("ec/rs_jax.py", "JAX002", "np.asarray(self.encode(data))"),
     ("ec/rs_jax.py", "JAX002", "np.asarray(self.decode(chunks"),
